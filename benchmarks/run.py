@@ -1,4 +1,4 @@
-"""Benchmark harness — one module per paper table/claim (DESIGN.md §6).
+"""Benchmark harness — one module per paper table/claim.
 
   python -m benchmarks.run            # all feature/system benches + roofline
   python -m benchmarks.run --only feature_latency
@@ -16,6 +16,7 @@ import traceback
 
 from benchmarks import common
 from benchmarks.common import emit, header
+from repro.compile_cache import enable_compile_cache
 
 BENCHES = [
     "feature_latency",   # §3.3 fraud: naive vs tuned vs featinsight
@@ -41,6 +42,7 @@ def main() -> None:
         help="CI mode: tiny sizes, one rep per timing, skip roofline",
     )
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         common.set_smoke(True)
 

@@ -20,6 +20,9 @@ from repro.core import (
 from repro.core.consistency import verify_view
 from repro.core.storage import TableSchema
 from repro.data import load_csv
+from repro.hostdevices import device_line
+
+print(device_line())
 
 # -- 1. import data (the paper's "Data Import" button) -----------------------
 SCHEMA = TableSchema(name="orders", key="user", ts="ts",

@@ -23,11 +23,10 @@ Run:  PYTHONPATH=src python examples/sharded_serving.py
 from __future__ import annotations
 
 # must precede any jax import: the mesh wants real (forced) host devices
-from repro.hostdevices import force_host_devices
+from repro.hostdevices import device_line, force_host_devices
 
 force_host_devices(8)
 
-import jax
 import numpy as np
 
 from repro.core import OnlineFeatureStore
@@ -56,7 +55,7 @@ def preload(store, tables) -> None:
 
 
 def main() -> None:
-    print(f"devices: {len(jax.devices())} (forced multi-device CPU)")
+    print(device_line())
     rng = np.random.default_rng(0)
     v = view()
     tables = multitable_stream(

@@ -62,6 +62,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.expr import Agg
 from repro.core.hashing import mix64
@@ -88,10 +89,10 @@ __all__ = [
     "topn_rank",
 ]
 
-POS_INF = jnp.float32(3.0e38)
-NEG_INF = jnp.float32(-3.0e38)
-_TS_MIN = jnp.int32(-2147483648)
-_TS_MAX = jnp.int32(2147483647)
+POS_INF = np.float32(3.0e38)
+NEG_INF = np.float32(-3.0e38)
+_TS_MIN = np.int32(-2147483648)
+_TS_MAX = np.int32(2147483647)
 
 TOPN_TAIL = 32  # contract: TOPN_FREQ windows are evaluated over <=32 rows
 
@@ -105,11 +106,11 @@ LANES: Tuple[str, ...] = ("sum", "count", "min", "max", "sumsq")
 NUM_STATS = len(LANES)
 
 _LANE_IDENT = {
-    "sum": jnp.float32(0.0),
-    "count": jnp.float32(0.0),
+    "sum": np.float32(0.0),
+    "count": np.float32(0.0),
     "min": POS_INF,
     "max": NEG_INF,
-    "sumsq": jnp.float32(0.0),
+    "sumsq": np.float32(0.0),
 }
 
 _LANE_LIFT = {
@@ -128,14 +129,33 @@ _LANE_COMBINE = {
     "sumsq": jnp.add,
 }
 
-# axis reduction consistent with each lane's combine (XLA-efficient form of
-# a combine tree over one array axis)
+def _tree_sum(x: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Sum over ``axis`` as an explicit pairwise tree (zero-padded to a
+    power of two).  A ``reduce`` leaves the f32 association to the
+    compiler, which picks it per program; the tree fixes it, so every
+    compiled query (fused multi-scenario, per-scenario, per-shard) adds a
+    row's values in the same order and agrees bit for bit."""
+    x = jnp.moveaxis(x, axis, -1)
+    n = x.shape[-1]
+    m = 1 << max(n - 1, 0).bit_length()
+    if m != n:
+        x = jnp.concatenate(
+            [x, jnp.zeros(x.shape[:-1] + (m - n,), x.dtype)], axis=-1
+        )
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        x = x[..., :h] + x[..., h:]
+    return x[..., 0]
+
+
+# axis reduction consistent with each lane's combine (a combine tree over
+# one array axis; order-free lanes use the compiler's reduce)
 _LANE_REDUCE = {
-    "sum": jnp.sum,
-    "count": jnp.sum,
+    "sum": _tree_sum,
+    "count": _tree_sum,
     "min": jnp.min,
     "max": jnp.max,
-    "sumsq": jnp.sum,
+    "sumsq": _tree_sum,
 }
 
 # scatter flavour consistent with each lane's combine (``.at[...].<kind>``)

@@ -22,8 +22,8 @@ import numpy as np
 
 __all__ = ["mix32", "mix64", "fold_hash", "mix32_np", "KeyPermutation"]
 
-_M1 = jnp.int32(-2048144789)   # 0x85ebca6b
-_M2 = jnp.int32(-1028477387)   # 0xc2b2ae35
+_M1 = np.int32(-2048144789)   # 0x85ebca6b
+_M2 = np.int32(-1028477387)   # 0xc2b2ae35
 
 
 def _as_i32(x: jnp.ndarray) -> jnp.ndarray:

@@ -160,7 +160,7 @@ def _host_ring(ring: st.RingStore, sharded: bool):
     a leading singleton shard axis is added for unsharded stores so every
     transform below is shard-shape-agnostic."""
     ts = np.asarray(ring.ts)
-    vals = np.asarray(ring.vals)
+    vals = st.to_logical(np.asarray(ring.vals), int(sharded))
     cur = np.asarray(ring.cursor)
     if not sharded:
         ts, vals, cur = ts[None], vals[None], cur[None]
@@ -168,11 +168,15 @@ def _host_ring(ring: st.RingStore, sharded: bool):
 
 
 def _mk_ring(ts, vals, cur, sharded: bool) -> st.RingStore:
+    """Inverse of :func:`_host_ring`: logical host arrays -> device ring
+    in the stored layout."""
     if not sharded:
         ts, vals, cur = ts[0], vals[0], cur[0]
     return st.RingStore(
         ts=jnp.asarray(np.ascontiguousarray(ts)),
-        vals=jnp.asarray(np.ascontiguousarray(vals)),
+        vals=jnp.asarray(
+            np.ascontiguousarray(st.to_stored(vals, int(sharded)))
+        ),
         cursor=jnp.asarray(np.ascontiguousarray(cur), jnp.int32),
     )
 
@@ -641,9 +645,8 @@ def _migrate_bucket(
     NB_o, NB_n = diff.old.bucket.num_buckets, diff.new.bucket.num_buckets
     bsize = diff.new.bucket.bucket_size
 
-    stats = np.asarray(bagg.stats)
-    bitmap = np.asarray(bagg.bitmap)
-    bucket = np.asarray(bagg.bucket)
+    host = pg.bucket_to_host(bagg, int(sharded))
+    stats, bitmap, bucket = host["stats"], host["bitmap"], host["bucket"]
     if not sharded:
         stats, bitmap, bucket = stats[None], bitmap[None], bucket[None]
 
@@ -661,8 +664,7 @@ def _migrate_bucket(
             ("tts", "tpos", "tval", "tvalid") if want_tail else ()
         )
         for nm in names:
-            a = np.asarray(getattr(bagg, nm))
-            fam[nm] = a if sharded else a[None]
+            fam[nm] = host[nm] if sharded else host[nm][None]
 
     if NB_n != NB_o:
         if np.any(bucket >= NB_o):
@@ -807,15 +809,10 @@ def _migrate_bucket(
     report.migrated.append(
         f"bucket[{NB_o}->{NB_n} x {bsize}, lanes {stats.shape[-2]}->{F_n}]"
     )
-    return pg.BucketAgg(
-        stats=jnp.asarray(np.ascontiguousarray(stats_out)),
-        bitmap=jnp.asarray(np.ascontiguousarray(bitmap_out)),
-        bucket=jnp.asarray(np.ascontiguousarray(bucket), jnp.int32),
-        size=bsize,
-        **{
-            k: jnp.asarray(np.ascontiguousarray(v))
-            for k, v in fam_kw.items()
-        },
+    return pg.bucket_from_host(
+        bsize,
+        dict(stats=stats_out, bitmap=bitmap_out, bucket=bucket, **fam_kw),
+        int(sharded),
     )
 
 

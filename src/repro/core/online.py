@@ -85,10 +85,10 @@ from repro.kernels import note_dispatch
 from repro.kernels.ingest.ops import fused_ingest_apply, resolve_ingest_impl
 from repro.obs import get_telemetry
 
-__all__ = ["OnlineState", "OnlineFeatureStore", "QueryProgram"]
+__all__ = ["OnlineState", "OnlineFeatureStore", "QueryProgram", "state_init"]
 
-_TS_MIN = jnp.int32(-2147483648)
-_POS_MAX = jnp.int32(2147483647)
+_TS_MIN = np.int32(-2147483648)
+_POS_MAX = np.int32(2147483647)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -111,6 +111,25 @@ class OnlineState:
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children)
+
+
+def state_init(layout: StoreLayout) -> OnlineState:
+    """Fresh (single-shard) device state for a layout plan.  A pure
+    function of the plan, so ``jax.eval_shape(lambda: state_init(layout))`` sizes
+    a deployment's state without allocating it."""
+    lanes = max(len(layout.primary.lanes), 1)
+    return OnlineState(
+        ring=st.ring_init(
+            layout.primary.ring_keys, layout.primary.capacity, lanes
+        ),
+        bagg=pg.bucket_init_plan(
+            layout.bucket, layout.primary.ring_keys, lanes
+        ),
+        sec=tuple(
+            st.ring_init(p.ring_keys, p.capacity, max(len(p.lanes), 1))
+            for p in layout.tables
+        ),
+    )
 
 
 class OnlineFeatureStore:
@@ -261,20 +280,7 @@ class OnlineFeatureStore:
         self._join_col_index = {c: i for i, c in enumerate(self._join_cols)}
 
     def _init_state(self) -> OnlineState:
-        lay = self.layout
-        sec = tuple(
-            st.ring_init(p.ring_keys, p.capacity, max(len(p.lanes), 1))
-            for p in lay.tables
-        )
-        return OnlineState(
-            ring=st.ring_init(
-                lay.primary.ring_keys, lay.primary.capacity, self.num_lanes
-            ),
-            bagg=pg.bucket_init_plan(
-                lay.bucket, lay.primary.ring_keys, self.num_lanes
-            ),
-            sec=sec,
-        )
+        return state_init(self.layout)
 
     def _build_fns(self) -> None:
         """(Re)wrap the pure kernels in jit.  Fresh wrappers on every
@@ -695,10 +701,19 @@ class OnlineFeatureStore:
         mids = b_lo[:, None] + 1 + jnp.arange(M, dtype=jnp.int32)[None, :]
         mvalid = mids < b_q[:, None]
         slots = mids % nb
-        stored = state.bagg.bucket[key[:, None], slots]
+        kk = jnp.broadcast_to(key[:, None], slots.shape)
+        bagg = state.bagg
+
+        def cells(x, lane_axis=None):
+            # scalar gathers from the stored (*small, K, NB) layout; the
+            # lane axis (when the array has one) is pinned to this wagg's
+            pin = None if lane_axis is None else {lane_axis: lane}
+            return x[st.cell_index(kk, slots, x.shape[:-2], pin)]
+
+        stored = bagg.bucket[kk, slots]
         ok = mvalid & (stored == mids)
-        ms = state.bagg.stats[key[:, None], slots, lane]   # (Q, M, NUM_STATS)
-        mb = state.bagg.bitmap[key[:, None], slots, lane]  # (Q, M)
+        ms = cells(bagg.stats, 0)    # (Q, M, NUM_STATS)
+        mb = cells(bagg.bitmap, 0)   # (Q, M)
         # merge-order families gather their persisted states alongside
         # (only for the spec that reads them — the arrays exist whenever
         # the layout planned them, asserted by the caller's family gate)
@@ -706,17 +721,17 @@ class OnlineFeatureStore:
         spec = agg_spec(wa.agg)
         if spec.state == "extreme":
             ext = {
-                "ts": state.bagg.xts[key[:, None], slots],       # (Q, M, 2)
-                "pos": state.bagg.xpos[key[:, None], slots],
-                "val": state.bagg.xval[key[:, None], slots, lane],
-                "has": state.bagg.xhas[key[:, None], slots],
+                "ts": cells(bagg.xts),          # (Q, M, 2)
+                "pos": cells(bagg.xpos),
+                "val": cells(bagg.xval, 0),
+                "has": cells(bagg.xhas),
             }
         elif spec.state == "tail":
             ext = {
-                "ts": state.bagg.tts[key[:, None], slots],       # (Q, M, T)
-                "pos": state.bagg.tpos[key[:, None], slots],
-                "val": state.bagg.tval[key[:, None], slots, lane],
-                "valid": state.bagg.tvalid[key[:, None], slots],
+                "ts": cells(bagg.tts),          # (Q, M, T)
+                "pos": cells(bagg.tpos),
+                "val": cells(bagg.tval, 0),
+                "valid": cells(bagg.tvalid),
             }
         return raw, ms, mb, ok, ext
 

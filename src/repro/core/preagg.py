@@ -33,8 +33,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import aggregates as ag
+from repro.core.storage import cell_index, to_logical, to_stored
 from repro.core.aggregates import (
     LANES,
     NEG_INF,
@@ -49,6 +51,8 @@ __all__ = [
     "bucket_init",
     "bucket_init_plan",
     "bucket_ingest",
+    "bucket_to_host",
+    "bucket_from_host",
     "row_stats",
     "stats_identity",
     "row_bitmap",
@@ -63,7 +67,7 @@ row_stats = ag.lanes_lift_stack
 stats_identity = ag.lanes_identity_stack
 
 
-_TS_EMPTY = jnp.int32(-2147483648)
+_TS_EMPTY = np.int32(-2147483648)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -71,8 +75,11 @@ _TS_EMPTY = jnp.int32(-2147483648)
 class BucketAgg:
     """Per-key ring of per-bucket partial aggregate states.
 
-    stats  : (K, NB, F, NUM_STATS) f32  stat-lane states (aggregates.LANES)
-    bitmap : (K, NB, F) int32   32-bit linear-counting bitmap per field
+    Arrays are in the stored key-minor layout of :mod:`repro.core.storage`
+    (small axes first, then key K, then bucket slot NB):
+
+    stats  : (F, NUM_STATS, K, NB) f32  stat-lane states (aggregates.LANES)
+    bitmap : (F, K, NB) int32   32-bit linear-counting bitmap per field
     bucket : (K, NB) int32      absolute bucket id held in each slot (-1 empty)
 
     Merge-order state families (``None`` unless the layout persists them —
@@ -81,13 +88,13 @@ class BucketAgg:
     seq    : (K,) int32         per-key arrival counter; the stored merge
                                 ``pos`` of a row is its per-key arrival
                                 index (mirrors the ring cursor)
-    xts/xpos/xhas : (K, NB, 2)  extreme winner per direction
+    xts/xpos/xhas : (2, K, NB)  extreme winner per direction
                                 (0 = oldest / FIRST, 1 = newest / LAST);
                                 winner row shared across lanes
-    xval   : (K, NB, F, 2)      the winner row's lane values
-    tts/tpos/tvalid : (K, NB, T) newest-first tail of the bucket's rows
+    xval   : (F, 2, K, NB)      the winner row's lane values
+    tts/tpos/tvalid : (T, K, NB) newest-first tail of the bucket's rows
                                 by (ts, pos), T = aggregates.TOPN_TAIL
-    tval   : (K, NB, F, T)      the tail rows' lane values
+    tval   : (F, T, K, NB)      the tail rows' lane values
     """
 
     stats: jnp.ndarray
@@ -120,34 +127,34 @@ class BucketAgg:
 
     @property
     def num_buckets(self) -> int:
-        return self.bucket.shape[1]
+        return self.bucket.shape[-1]
 
 
 def bucket_init(
     num_keys: int, num_buckets: int, width: int, size: int,
     *, extreme: bool = False, tail: bool = False,
 ) -> BucketAgg:
+    K, NB, T = num_keys, num_buckets, TOPN_TAIL
     kw = {}
     if extreme or tail:
-        kw["seq"] = jnp.zeros((num_keys,), jnp.int32)
+        kw["seq"] = jnp.zeros((K,), jnp.int32)
     if extreme:
-        kw["xts"] = jnp.full((num_keys, num_buckets, 2), _TS_EMPTY)
-        kw["xpos"] = jnp.zeros((num_keys, num_buckets, 2), jnp.int32)
-        kw["xval"] = jnp.zeros(
-            (num_keys, num_buckets, width, 2), jnp.float32
-        )
-        kw["xhas"] = jnp.zeros((num_keys, num_buckets, 2), bool)
+        kw["xts"] = jnp.full((2, K, NB), _TS_EMPTY)
+        kw["xpos"] = jnp.zeros((2, K, NB), jnp.int32)
+        kw["xval"] = jnp.zeros((width, 2, K, NB), jnp.float32)
+        kw["xhas"] = jnp.zeros((2, K, NB), bool)
     if tail:
-        kw["tts"] = jnp.full((num_keys, num_buckets, TOPN_TAIL), _TS_EMPTY)
-        kw["tpos"] = jnp.zeros((num_keys, num_buckets, TOPN_TAIL), jnp.int32)
-        kw["tval"] = jnp.zeros(
-            (num_keys, num_buckets, width, TOPN_TAIL), jnp.float32
-        )
-        kw["tvalid"] = jnp.zeros((num_keys, num_buckets, TOPN_TAIL), bool)
+        kw["tts"] = jnp.full((T, K, NB), _TS_EMPTY)
+        kw["tpos"] = jnp.zeros((T, K, NB), jnp.int32)
+        kw["tval"] = jnp.zeros((width, T, K, NB), jnp.float32)
+        kw["tvalid"] = jnp.zeros((T, K, NB), bool)
+    ident = stats_identity(())  # (NUM_STATS,)
     return BucketAgg(
-        stats=stats_identity((num_keys, num_buckets, width)),
-        bitmap=jnp.zeros((num_keys, num_buckets, width), jnp.int32),
-        bucket=jnp.full((num_keys, num_buckets), jnp.int32(-1)),
+        stats=jnp.broadcast_to(
+            ident[None, :, None, None], (width, NUM_STATS, K, NB)
+        ),
+        bitmap=jnp.zeros((width, K, NB), jnp.int32),
+        bucket=jnp.full((K, NB), -1, jnp.int32),
         size=size,
         **kw,
     )
@@ -162,6 +169,34 @@ def bucket_init_plan(plan, num_keys: int, width: int) -> BucketAgg:
         num_keys, plan.num_buckets, width, plan.bucket_size,
         extreme=getattr(plan, "extreme", False),
         tail=getattr(plan, "tail", False),
+    )
+
+
+def bucket_to_host(bagg: BucketAgg, lead: int = 0) -> dict:
+    """Every array of a bucket store as host numpy in the logical per-key
+    layout ((*batch, K, NB, *small)); absent families are omitted."""
+    out = {}
+    for name in ("stats", "bitmap", "bucket", "seq", "xts", "xpos", "xval",
+                 "xhas", "tts", "tpos", "tval", "tvalid"):
+        a = getattr(bagg, name)
+        if a is not None:
+            out[name] = to_logical(np.asarray(a), lead)
+    return out
+
+
+def bucket_from_host(size: int, arrays: dict, lead: int = 0) -> BucketAgg:
+    """Inverse of :func:`bucket_to_host`: logical host arrays -> a device
+    bucket store in the stored layout."""
+    dt = {"bucket": jnp.int32, "seq": jnp.int32}
+    return BucketAgg(
+        size=size,
+        **{
+            k: jnp.asarray(
+                np.ascontiguousarray(to_stored(np.asarray(v), lead)),
+                dt.get(k),
+            )
+            for k, v in arrays.items()
+        },
     )
 
 
@@ -193,6 +228,13 @@ def _lane_scatter(target, index, update, lane_idx: int, lane: str):
     return at.max(update, mode="drop")
 
 
+def _at(x: jnp.ndarray, k: jnp.ndarray, s: jnp.ndarray):
+    """``x.at`` over cell (k, s) of every small-axis position of a stored
+    (*small, K, NB) array: gathers/sets (N, *small) blocks, one scalar
+    gather/scatter each (see :func:`repro.core.storage.cell_index`)."""
+    return x.at[cell_index(k, s, x.shape[:-2])]
+
+
 def bucket_ingest(
     agg: BucketAgg,
     key: jnp.ndarray,   # (N,) int32 sorted by (key, ts)
@@ -206,8 +248,10 @@ def bucket_ingest(
     stored bucket id differs from the incoming id are reset first (ring
     reuse) — the scatter analogue of OpenMLDB finalizing an old bucket.
 
-    All scatters route padding/no-op rows to out-of-bounds indices with
-    mode="drop", so duplicate-index .set hazards cannot occur.
+    Each valid segment owns a distinct (key, slot) cell, so every state
+    array is updated by one gather, a combine, and one set: race-free, and
+    bit-identical to scatter-combining into the (reset) stored state.
+    Padding/no-op rows route to out-of-bounds keys with mode="drop".
     """
     nb = agg.num_buckets
     K = agg.bucket.shape[0]
@@ -249,25 +293,20 @@ def bucket_ingest(
     k_v = jnp.where(seg_valid, rep_key, jnp.int32(K))
     s_v = rep_slot
 
-    # --- reset slots holding a stale bucket --------------------------------
+    # --- slots holding a stale bucket restart from the identity ------------
     stored = agg.bucket.at[k_v, s_v].get(mode="fill", fill_value=-1)
     stale = seg_valid & (stored != rep_bucket) & (stored != -1)
-    k_st = jnp.where(stale, rep_key, jnp.int32(K))
-    stats = agg.stats.at[k_st, rep_slot].set(
-        stats_identity((n, width)), mode="drop"
-    )
-    bitmap = agg.bitmap.at[k_st, rep_slot].set(
-        jnp.zeros((n, width), jnp.int32), mode="drop"
-    )
 
-    # --- combine the new segment aggregates --------------------------------
-    for i, lane in enumerate(LANES):
-        stats = _lane_scatter(stats, (k_v, s_v), rep_stats[..., i], i, lane)
-
-    # bitmap OR: (key, slot) pairs are unique among valid segments within a
-    # batch (batch spans < NB buckets), so gather-OR-set is race-free.
-    gathered = bitmap.at[k_v, s_v].get(mode="fill", fill_value=0)
-    bitmap = bitmap.at[k_v, s_v].set(gathered | rep_bm, mode="drop")
+    g_stats = _at(agg.stats, k_v, s_v).get(mode="fill", fill_value=0.0)
+    g_stats = jnp.where(
+        stale[:, None, None], stats_identity((n, width)), g_stats
+    )
+    stats = _at(agg.stats, k_v, s_v).set(
+        ag.lanes_combine_stack(g_stats, rep_stats), mode="drop"
+    )
+    g_bm = _at(agg.bitmap, k_v, s_v).get(mode="fill", fill_value=0)
+    g_bm = jnp.where(stale[:, None], 0, g_bm)
+    bitmap = _at(agg.bitmap, k_v, s_v).set(g_bm | rep_bm, mode="drop")
 
     bucket_ids = agg.bucket.at[k_v, s_v].set(rep_bucket, mode="drop")
 
@@ -294,35 +333,31 @@ def bucket_ingest(
         c_pos = pos[c_rows]
         c_val = vals[c_rows].transpose(0, 2, 1)               # (N, F, 2)
 
-        xts = xts.at[k_st, rep_slot].set(
-            jnp.full((n, 2), _TS_EMPTY), mode="drop")
-        xpos = xpos.at[k_st, rep_slot].set(
-            jnp.zeros((n, 2), jnp.int32), mode="drop")
-        xval = xval.at[k_st, rep_slot].set(
-            jnp.zeros((n, width, 2), jnp.float32), mode="drop")
-        xhas = xhas.at[k_st, rep_slot].set(
-            jnp.zeros((n, 2), bool), mode="drop")
-
-        g_ts = xts.at[k_v, s_v].get(mode="fill", fill_value=_TS_EMPTY)
-        g_pos = xpos.at[k_v, s_v].get(mode="fill", fill_value=0)
-        g_val = xval.at[k_v, s_v].get(mode="fill", fill_value=0.0)
-        g_has = xhas.at[k_v, s_v].get(mode="fill", fill_value=False)
+        st2 = stale[:, None]
+        g_ts = _at(xts, k_v, s_v).get(mode="fill", fill_value=_TS_EMPTY)
+        g_pos = _at(xpos, k_v, s_v).get(mode="fill", fill_value=0)
+        g_val = _at(xval, k_v, s_v).get(mode="fill", fill_value=0.0)
+        g_has = _at(xhas, k_v, s_v).get(mode="fill", fill_value=False)
+        g_ts = jnp.where(st2, _TS_EMPTY, g_ts)
+        g_pos = jnp.where(st2, 0, g_pos)
+        g_val = jnp.where(st2[:, None], 0.0, g_val)
+        g_has = g_has & ~st2
 
         older = (c_ts < g_ts) | ((c_ts == g_ts) & (c_pos < g_pos))
         newer = (c_ts > g_ts) | ((c_ts == g_ts) & (c_pos > g_pos))
         want = jnp.stack([older[:, 0], newer[:, 1]], axis=-1)
         take = ~g_has | want                                  # (N, 2)
 
-        xts = xts.at[k_v, s_v].set(
+        xts = _at(xts, k_v, s_v).set(
             jnp.where(take, c_ts, g_ts), mode="drop")
-        xpos = xpos.at[k_v, s_v].set(
+        xpos = _at(xpos, k_v, s_v).set(
             jnp.where(take, c_pos, g_pos), mode="drop")
-        xval = xval.at[k_v, s_v].set(
+        xval = _at(xval, k_v, s_v).set(
             jnp.where(take[:, None, :], c_val, g_val), mode="drop")
-        xhas = xhas.at[k_v, s_v].set(jnp.ones((n, 2), bool), mode="drop")
+        xhas = _at(xhas, k_v, s_v).set(jnp.ones((n, 2), bool), mode="drop")
 
     if tts is not None:
-        T = tts.shape[-1]
+        T = tts.shape[0]
         # newest-first candidate rows of each segment (row order is
         # (ts, pos) ascending, so counting back from end_rows is exact)
         t_rows = end_rows[:, None] - jnp.arange(T, dtype=jnp.int32)[None, :]
@@ -333,19 +368,15 @@ def bucket_ingest(
         ct_val = jnp.where(
             in_seg[:, None, :], vals[t_rc].transpose(0, 2, 1), 0.0)
 
-        tts = tts.at[k_st, rep_slot].set(
-            jnp.full((n, T), _TS_EMPTY), mode="drop")
-        tpos = tpos.at[k_st, rep_slot].set(
-            jnp.zeros((n, T), jnp.int32), mode="drop")
-        tval = tval.at[k_st, rep_slot].set(
-            jnp.zeros((n, width, T), jnp.float32), mode="drop")
-        tvalid = tvalid.at[k_st, rep_slot].set(
-            jnp.zeros((n, T), bool), mode="drop")
-
-        gt_ts = tts.at[k_v, s_v].get(mode="fill", fill_value=_TS_EMPTY)
-        gt_pos = tpos.at[k_v, s_v].get(mode="fill", fill_value=0)
-        gt_val = tval.at[k_v, s_v].get(mode="fill", fill_value=0.0)
-        gt_valid = tvalid.at[k_v, s_v].get(mode="fill", fill_value=False)
+        st2 = stale[:, None]
+        gt_ts = _at(tts, k_v, s_v).get(mode="fill", fill_value=_TS_EMPTY)
+        gt_pos = _at(tpos, k_v, s_v).get(mode="fill", fill_value=0)
+        gt_val = _at(tval, k_v, s_v).get(mode="fill", fill_value=0.0)
+        gt_valid = _at(tvalid, k_v, s_v).get(mode="fill", fill_value=False)
+        gt_ts = jnp.where(st2, _TS_EMPTY, gt_ts)
+        gt_pos = jnp.where(st2, 0, gt_pos)
+        gt_val = jnp.where(st2[:, None], 0.0, gt_val)
+        gt_valid = gt_valid & ~st2
 
         m_ts = jnp.concatenate(
             [ct_ts, jnp.where(gt_valid, gt_ts, _TS_EMPTY)], axis=1)
@@ -366,12 +397,12 @@ def bucket_ingest(
         s_val = jnp.take_along_axis(
             m_val, perm[:, None, :], axis=2)[:, :, :T]
 
-        tts = tts.at[k_v, s_v].set(s_ts, mode="drop")
-        tpos = tpos.at[k_v, s_v].set(
+        tts = _at(tts, k_v, s_v).set(s_ts, mode="drop")
+        tpos = _at(tpos, k_v, s_v).set(
             jnp.where(s_valid, s_pos, 0), mode="drop")
-        tval = tval.at[k_v, s_v].set(
+        tval = _at(tval, k_v, s_v).set(
             jnp.where(s_valid[:, None, :], s_val, 0.0), mode="drop")
-        tvalid = tvalid.at[k_v, s_v].set(s_valid, mode="drop")
+        tvalid = _at(tvalid, k_v, s_v).set(s_valid, mode="drop")
 
     if seq is not None:
         seq = seq.at[key].add(jnp.ones_like(key), mode="drop")

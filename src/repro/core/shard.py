@@ -78,7 +78,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.hashing import KeyPermutation
 from repro.core.layout import StoreLayout, plan_layout
-from repro.core.online import OnlineFeatureStore, OnlineState
+from repro.core.online import OnlineFeatureStore, OnlineState, state_init
 from repro.kernels import note_dispatch
 from repro.kernels.route.ops import route_rank
 
@@ -217,11 +217,18 @@ class ShardedOnlineStore(OnlineFeatureStore):
             self.sharding = NamedSharding(self.mesh, P("shard"))
 
     def _init_state(self) -> OnlineState:
-        # stack S identical fresh per-shard states, partition over the mesh
-        single = super()._init_state()
-        return self._place_state(
-            jax.tree.map(lambda x: jnp.stack([x] * self.num_shards), single)
-        )
+        # S identical fresh per-shard states, built in place on the mesh:
+        # each device materializes only its own shards (a host-side stack
+        # would first hold all S on one device)
+        S = self.num_shards
+
+        def init():
+            return jax.tree.map(
+                lambda x: jnp.broadcast_to(x, (S,) + x.shape),
+                state_init(self.layout),
+            )
+
+        return jax.jit(init, out_shardings=self.sharding)()
 
     def _place_state(self, state: OnlineState) -> OnlineState:
         return jax.device_put(
@@ -240,16 +247,29 @@ class ShardedOnlineStore(OnlineFeatureStore):
         # shape bucket) below and must re-trace after a layout adoption,
         # exactly like the base query fns
         self._fused_fns: Dict[Tuple, object] = {}
-        self._ingest_fn = jax.jit(
-            jax.vmap(self._ingest_pure), donate_argnums=(0,)
-        )
+        self._ingest_fn = self._jit_ingest(self._ingest_pure)
         self._sec_ingest_fns = {
-            i: jax.jit(
-                jax.vmap(functools.partial(self._sec_ingest_pure, index=i)),
-                donate_argnums=(0,),
+            i: self._jit_ingest(
+                functools.partial(self._sec_ingest_pure, index=i)
             )
             for i in range(len(self._ring_plans))
         }
+
+    def _jit_ingest(self, fn):
+        """Per-shard ingest, vmapped over each device's local shards under
+        ``shard_map``: the Pallas ingest kernel cannot be partitioned by
+        GSPMD, and every argument (state and routed batch) is already
+        laid out ``P('shard')``, so each device updates its own state in
+        place with no collective."""
+        spec = P("shard")
+        return jax.jit(
+            jax.shard_map(
+                jax.vmap(fn), mesh=self.mesh,
+                in_specs=(spec, spec, spec, spec), out_specs=spec,
+                check_vma=False,  # the kernel's outputs carry no vma
+            ),
+            donate_argnums=(0,),
+        )
 
     def _jit_query(self, fn):
         """Sharded query programs run vmapped over the leading shard axis
@@ -572,7 +592,12 @@ class ShardedOnlineStore(OnlineFeatureStore):
         )
         shard = routed % S
         local = routed // S
-        rank, counts = route_rank(shard, num_shards=S)
+        # the route kernel runs replicated (every device ranks the whole
+        # batch) under shard_map: GSPMD cannot partition a Pallas call
+        rank, counts = jax.shard_map(
+            functools.partial(route_rank, num_shards=S), mesh=self.mesh,
+            in_specs=P(), out_specs=(P(), P()), check_vma=False,
+        )(shard)
         overflow = jnp.any(counts > B)
         slot = jnp.minimum(rank, B - 1)
 

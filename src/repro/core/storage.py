@@ -7,7 +7,8 @@ invariant the skiplist buys: **per-key, timestamp-ordered, O(1)-appendable
 recent history**.  We realize it as a structure-of-arrays ring buffer:
 
   ts    : (K, C)     int32   per-key ring of row timestamps
-  vals  : (K, C, F)  float32 per-key ring of encoded row payloads
+  vals  : (F, K, C)  float32 per-key ring of encoded row payloads, one
+                             (K, C) plane per lane (see "Stored layout")
   cursor: (K,)       int32   next write slot (monotone; slot = cursor % C)
 
 * "Compact row encoding"  -> the codec below: fixed-width numeric fields are
@@ -37,8 +38,69 @@ from repro.core.hashing import fold_hash
 
 __all__ = [
     "TableSchema", "Database", "RowCodec", "RingStore",
-    "ring_init", "ring_ingest",
+    "ring_init", "ring_ingest", "cell_index", "to_logical", "to_stored",
 ]
+
+
+# ---------------------------------------------------------------------------
+# Stored layout of per-key state
+# ---------------------------------------------------------------------------
+#
+# Every per-key state array (ring rows here, bucket pre-aggregates in
+# preagg.py) is stored *key-minor*: its small axes (lanes F, the NUM_STATS
+# stat lanes, merge-order slots) lead, and its two long axes -- the key K,
+# then the per-key slot axis (ring slot C or bucket slot NB) -- are the two
+# minor ones.  A TPU tiles the two minor axes of an array as (8 sublanes x
+# 128 lanes), so (K, slot) tiles densely where a minor F=2 or NUM_STATS=5
+# axis would pad 64x or 25x.  Device code addresses single cells with
+# scalar indices (``cell_index``) and never slices a window over a small
+# leading axis: XLA answers such a window by relaying out the whole array.
+# Host code (migration, backfill) works in the logical per-key layout
+# (K, slot, *small) and converts at its edges with ``to_logical`` /
+# ``to_stored``; ``lead`` counts leading batch axes (the shard axis).
+
+
+def to_logical(x: np.ndarray, lead: int = 0) -> np.ndarray:
+    """Stored (*batch, *small, K, SLOT) -> logical (*batch, K, SLOT, *small)."""
+    if x.ndim - lead <= 2:
+        return x
+    return np.moveaxis(x, (-2, -1), (lead, lead + 1))
+
+
+def to_stored(x: np.ndarray, lead: int = 0) -> np.ndarray:
+    """Logical (*batch, K, SLOT, *small) -> stored (*batch, *small, K, SLOT)."""
+    if x.ndim - lead <= 2:
+        return x
+    return np.moveaxis(x, (lead, lead + 1), (-2, -1))
+
+
+def cell_index(k, s, small: Tuple[int, ...], pin: Optional[Dict[int, int]] = None):
+    """Scalar-element index into a stored ``(*small, K, SLOT)`` array.
+
+    For every position of ``k``/``s`` (same shape B) and of each swept small
+    axis, addresses cell ``(..., k, s)``; ``pin`` fixes small axes to one
+    value instead of sweeping them.  ``x[cell_index(...)]`` (or
+    ``x.at[...]``) yields shape ``B + swept`` -- the logical per-key order
+    -- through one scalar gather/scatter.
+    """
+    pin = pin or {}
+    k = jnp.asarray(k, jnp.int32)
+    s = jnp.asarray(s, jnp.int32)
+    b = k.shape
+    swept = tuple(n for ax, n in enumerate(small) if ax not in pin)
+    shape = b + swept
+    out = []
+    d = len(b)
+    for ax in range(len(small)):
+        if ax in pin:
+            out.append(jnp.full(shape, pin[ax], jnp.int32))
+        else:
+            out.append(jax.lax.broadcasted_iota(jnp.int32, shape, d))
+            d += 1
+    tail = (1,) * len(swept)
+    out.append(jnp.broadcast_to(k.reshape(b + tail), shape))
+    out.append(jnp.broadcast_to(s.reshape(b + tail), shape))
+    return tuple(out)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,7 +203,7 @@ class RingStore:
     """Per-key timestamp-ordered ring buffers (functional)."""
 
     ts: jnp.ndarray       # (K, C) int32
-    vals: jnp.ndarray     # (K, C, F) f32
+    vals: jnp.ndarray     # (F, K, C) f32, stored layout
     cursor: jnp.ndarray   # (K,) int32, monotone row count per key
 
     def tree_flatten(self):
@@ -153,21 +215,21 @@ class RingStore:
 
     @property
     def num_keys(self) -> int:
-        return self.ts.shape[0]
+        return self.ts.shape[-2]
 
     @property
     def capacity(self) -> int:
-        return self.ts.shape[1]
+        return self.ts.shape[-1]
 
     @property
     def width(self) -> int:
-        return self.vals.shape[2]
+        return self.vals.shape[-3]
 
 
 def ring_init(num_keys: int, capacity: int, width: int) -> RingStore:
     return RingStore(
-        ts=jnp.full((num_keys, capacity), jnp.int32(-2147483648)),
-        vals=jnp.zeros((num_keys, capacity, width), jnp.float32),
+        ts=jnp.full((num_keys, capacity), -2147483648, jnp.int32),
+        vals=jnp.zeros((width, num_keys, capacity), jnp.float32),
         cursor=jnp.zeros((num_keys,), jnp.int32),
     )
 
@@ -196,7 +258,9 @@ def ring_ingest(
 
     slot = (store.cursor[key] + rank) % cap
     ts_new = store.ts.at[key, slot].set(ts, mode="drop")
-    vals_new = store.vals.at[key, slot].set(vals, mode="drop")
+    vals_new = store.vals.at[cell_index(key, slot, (store.width,))].set(
+        vals, mode="drop"
+    )
     # per-key appended count = segment length; scatter-add ones
     cursor_new = store.cursor.at[key].add(jnp.ones((n,), jnp.int32))
     return RingStore(ts=ts_new, vals=vals_new, cursor=cursor_new)
@@ -216,8 +280,7 @@ def ring_gather(
     slots = (cur[:, None] - cap + offs) % cap
     age_rank = cur[:, None] - cap + offs  # absolute row index; <0 => never written
     valid = age_rank >= 0
-    ts = jnp.take_along_axis(store.ts[keys], slots, axis=1)
-    vals = jnp.take_along_axis(
-        store.vals[keys], slots[..., None], axis=1
-    )
+    kk = jnp.broadcast_to(keys[:, None], slots.shape)
+    ts = store.ts[kk, slots]
+    vals = store.vals[cell_index(kk, slots, (store.width,))]
     return ts, vals, valid

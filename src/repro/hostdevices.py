@@ -12,7 +12,7 @@ from __future__ import annotations
 import os
 import sys
 
-__all__ = ["force_host_devices"]
+__all__ = ["force_host_devices", "device_line"]
 
 
 def force_host_devices(n: int = 8) -> bool:
@@ -31,3 +31,12 @@ def force_host_devices(n: int = 8) -> bool:
         f"{flags} --xla_force_host_platform_device_count={n}"
     ).strip()
     return True
+
+
+def device_line() -> str:
+    """One line naming what JAX runs on: count, platform and device kind
+    (imports jax, so call it after :func:`force_host_devices`)."""
+    import jax
+
+    devs = jax.devices()
+    return f"devices: {len(devs)} x {devs[0].platform} ({devs[0].device_kind})"

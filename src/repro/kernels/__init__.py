@@ -62,3 +62,16 @@ def note_dispatch(kernel: str, impl: str) -> None:
         "1",
         labels=("kernel", "impl"),
     ).inc(1.0, kernel=kernel, impl=impl)
+
+
+def note_cutover(kernel: str) -> None:
+    """Count a size cut-over: ``impl="auto"`` chose the kernel's backend
+    but the call outgrew it and went to XLA (``kernel_cutover_total``)."""
+    from repro.obs.telemetry import get_telemetry
+
+    get_telemetry().metrics.counter(
+        "kernel_cutover_total",
+        "kernel calls sent to XLA by a size cut-over on the kernel's backend",
+        "1",
+        labels=("kernel",),
+    ).inc(1.0, kernel=kernel)

@@ -30,9 +30,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_kernel", "flash_attention_pallas"]
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.4.38; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 _NEG_INF = -1.0e30
 
 
@@ -166,7 +163,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import note_dispatch
-from repro.kernels.ingest.ingest import fused_ingest_pallas
+from repro.kernels.ingest.ingest import KEY_GROUP, fused_ingest_pallas
 from repro.kernels.ingest.ref import fused_ingest_ref
 
 __all__ = ["fused_ingest", "fused_ingest_apply", "resolve_ingest_impl"]
@@ -69,12 +69,12 @@ def _ingest_plan(
     num_buckets: int,
     bucket_size: int,
 ) -> Tuple[jnp.ndarray, ...]:
-    """The 9 (N,) int32 scalar-prefetch arrays driving the kernel's pass.
+    """The 8 (N,) int32 scalar-prefetch arrays driving the kernel's pass.
 
     Sentinel pad rows (key == num_keys) inherit the nearest real row's
     (key, bucket) — forward fill, then backward fill for leading pads —
     so the kernel's block index never jumps to a pad-only block and every
-    key's blocks are visited in one consecutive run.  Pad rows write
+    key group's blocks are visited in one consecutive run.  Pad rows write
     nothing (``valid`` gates every state mutation).
     """
     n = key.shape[0]
@@ -95,6 +95,8 @@ def _ingest_plan(
 
     first = jnp.ones((1,), bool)
     kchange = jnp.concatenate([first, ckey[1:] != ckey[:-1]])
+    grp = ckey // jnp.int32(KEY_GROUP)
+    gchange = jnp.concatenate([first, grp[1:] != grp[:-1]])
     schange = kchange | jnp.concatenate([first, cbid[1:] != cbid[:-1]])
     send = jnp.concatenate([schange[1:], first])
     seg_id = jnp.cumsum(schange.astype(jnp.int32)) - 1
@@ -111,17 +113,17 @@ def _ingest_plan(
 
     as_i32 = lambda x: x.astype(jnp.int32)  # noqa: E731
     return (
-        ckey, as_i32(kchange), as_i32(schange), as_i32(flush),
-        as_i32(valid), slot_r, cnt, cbid, slot_b,
+        ckey, as_i32(gchange), as_i32(schange), as_i32(flush),
+        as_i32(valid), slot_r, cbid, slot_b,
     )
 
 
 def fused_ingest(
     ring_ts: jnp.ndarray,    # (K, C) int32
-    ring_vals: jnp.ndarray,  # (K, C, F) f32
+    ring_vals: jnp.ndarray,  # (F, K, C) f32 (stored layout)
     cursor: jnp.ndarray,     # (K,) int32
-    bstats: jnp.ndarray,     # (K, NB, F, NUM_STATS) f32
-    bbitmap: jnp.ndarray,    # (K, NB, F) int32
+    bstats: jnp.ndarray,     # (F, NUM_STATS, K, NB) f32
+    bbitmap: jnp.ndarray,    # (F, K, NB) int32
     bbucket: jnp.ndarray,    # (K, NB) int32
     key: jnp.ndarray,        # (N,) int32 sorted by (key, ts); pad key == K
     ts: jnp.ndarray,         # (N,) int32
@@ -160,10 +162,14 @@ def fused_ingest_apply(
         num_keys=ring_ts.shape[0], capacity=ring_ts.shape[1],
         num_buckets=bbucket.shape[1], bucket_size=bucket_size,
     )
-    return fused_ingest_pallas(
-        ring_ts, ring_vals, cursor, bstats, bbitmap, bbucket,
+    rts, rvals, bst, bbm, bid = fused_ingest_pallas(
+        ring_ts, ring_vals, bstats, bbitmap, bbucket,
         ts, vals, plan, interpret=interpret,
     )
+    # the cursor advance is a (K,) scatter-add, as in ring_ingest (pad
+    # rows carry key == K and drop)
+    cur = cursor.at[key].add(jnp.ones_like(key), mode="drop")
+    return rts, rvals, cur, bst, bbm, bid
 
 
 _fused_ingest = functools.partial(

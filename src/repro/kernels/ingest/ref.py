@@ -21,10 +21,10 @@ __all__ = ["fused_ingest_ref"]
 
 def fused_ingest_ref(
     ring_ts: jnp.ndarray,    # (K, C) int32
-    ring_vals: jnp.ndarray,  # (K, C, F) f32
+    ring_vals: jnp.ndarray,  # (F, K, C) f32 (stored layout)
     cursor: jnp.ndarray,     # (K,) int32
-    bstats: jnp.ndarray,     # (K, NB, F, NUM_STATS) f32
-    bbitmap: jnp.ndarray,    # (K, NB, F) int32
+    bstats: jnp.ndarray,     # (F, NUM_STATS, K, NB) f32
+    bbitmap: jnp.ndarray,    # (F, K, NB) int32
     bbucket: jnp.ndarray,    # (K, NB) int32
     key: jnp.ndarray,        # (N,) int32 sorted by (key, ts); pad key == K
     ts: jnp.ndarray,         # (N,) int32

@@ -18,18 +18,19 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import note_dispatch, vmem_row_budget
+from repro.kernels import note_cutover, note_dispatch, vmem_row_budget
 from repro.kernels.route.ref import route_rank_ref
 from repro.kernels.route.route import ROUTE_LANE, route_rank_pallas
 
 __all__ = ["route_rank"]
 
 # The route kernel holds the whole batch resident: the (rows, 128) id
-# tile, its within-row cumsum, the across-row running totals, and the
-# mask temporary — 4 live i32 arrays.  Unlike the fold kernel it does not
-# stream tiles, so residency IS the cap; serving batches sit orders of
-# magnitude below it.
-_ROUTE_PALLAS_MAX_ROWS = ROUTE_LANE * vmem_row_budget(4)
+# tile, the output tile, the mask, and the two log-step prefix passes
+# (running sum, rolled copy, iota guard) — 8 live i32 arrays.  Unlike the
+# fold kernel it does not stream tiles, so residency IS the cap (2^19
+# rows, compiled for a v5e); serving batches sit orders of magnitude
+# below it.
+_ROUTE_PALLAS_MAX_ROWS = ROUTE_LANE * vmem_row_budget(8)
 
 
 def route_rank(
@@ -42,12 +43,12 @@ def route_rank(
     """(rank (N,) int32, counts (S,) int32): rank of each row within its
     shard in batch order, and rows per shard."""
     if impl == "auto":
-        impl = (
-            "pallas"
-            if jax.default_backend() == "tpu"
-            and shard.shape[0] <= _ROUTE_PALLAS_MAX_ROWS
-            else "xla"
-        )
+        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+        if impl == "pallas" and shard.shape[0] > _ROUTE_PALLAS_MAX_ROWS:
+            # the batch outgrows the kernel's VMEM residency: counted, so
+            # the size cut-over is never a silent fallback
+            impl = "xla"
+            note_cutover("route_rank")
     note_dispatch("route_rank", impl)
     return _route_rank(
         shard, num_shards=num_shards, impl=impl, interpret=interpret

@@ -8,8 +8,10 @@ On TPU that is one VMEM-resident pass per shard:
 * the shard-id batch lives as a (rows, 128) int32 tile (lane-major
   flattening of the 1-D batch, padded with an inert id);
 * grid step ``s`` masks the tile to shard ``s`` and computes the
-  flat-order exclusive prefix count from two cumsums (within-row along
-  lanes + across rows of the per-row totals) — no gather, no sort;
+  flat-order exclusive prefix count in two log-step passes of shifted
+  adds (``pltpu.roll`` + an iota guard): along lanes within each row, then
+  along rows over the per-row totals — no cumsum (Mosaic has no lowering
+  for it), no gather, no sort;
 * each step merges its ranks into the output tile, so after S steps every
   row holds its rank.  S grid steps pipeline; the tile stays resident.
 
@@ -32,14 +34,28 @@ __all__ = ["route_rank_pallas", "ROUTE_LANE"]
 ROUTE_LANE = 128  # f32/i32 native lane width — tile rows are (8, 128)
 
 
+def _prefix_sum(x: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Inclusive prefix sum along ``axis`` by log-step shifted adds:
+    ``x += x shifted by d`` for d = 1, 2, 4, ... (exact for integers)."""
+    n = x.shape[axis]
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    d = 1
+    while d < n:
+        x = x + jnp.where(idx >= d, pltpu.roll(x, d, axis), 0)
+        d *= 2
+    return x
+
+
 def _route_rank_kernel(shard_ref, rank_ref):
     s = pl.program_id(0)
     mask = (shard_ref[...] == s).astype(jnp.int32)  # (rows, LANE)
     # flat-order exclusive prefix count: earlier lanes of this row plus
     # all lanes of earlier rows
-    within = jnp.cumsum(mask, axis=1) - mask
-    row_tot = jnp.sum(mask, axis=1, keepdims=True)          # (rows, 1)
-    prior = jnp.cumsum(row_tot, axis=0) - row_tot           # (rows, 1)
+    within = _prefix_sum(mask, 1) - mask
+    row_tot = jnp.broadcast_to(
+        jnp.sum(mask, axis=1, keepdims=True), mask.shape
+    )
+    prior = _prefix_sum(row_tot, 0) - row_tot
     rank_s = within + prior
 
     @pl.when(s == 0)

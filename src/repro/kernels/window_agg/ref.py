@@ -21,9 +21,12 @@ import math
 from typing import Sequence, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
-POS_INF = jnp.float32(3.0e38)
-NEG_INF = jnp.float32(-3.0e38)
+from repro.core.storage import cell_index
+
+POS_INF = np.float32(3.0e38)
+NEG_INF = np.float32(-3.0e38)
 
 __all__ = [
     "window_stats_ref",
@@ -89,8 +92,8 @@ def fold_levels_ref(
 
 def window_stats_ref(
     ring_ts: jnp.ndarray,      # (K, C) int32 (slot order arbitrary)
-    ring_lanes: jnp.ndarray,   # (K, C, L) f32
-    bagg_stats: jnp.ndarray,   # (K, NB, L, 5) f32
+    ring_lanes: jnp.ndarray,   # (L, K, C) f32 (stored layout)
+    bagg_stats: jnp.ndarray,   # (L, 5, K, NB) f32 (stored layout)
     bagg_bucket: jnp.ndarray,  # (K, NB) int32 (-1 empty)
     q_key: jnp.ndarray,        # (Q,) int32
     q_ts: jnp.ndarray,         # (Q,) int32
@@ -100,10 +103,15 @@ def window_stats_ref(
 ) -> jnp.ndarray:
     """Returns (Q, NW, L, 5) composed stats."""
     B = jnp.int32(bucket_size)
-    ts = ring_ts[q_key]          # (Q, C)
-    lanes = ring_lanes[q_key]    # (Q, C, L)
-    bstats = bagg_stats[q_key]   # (Q, NB, L, 5)
-    bids = bagg_bucket[q_key]    # (Q, NB)
+    def rows(x, width):  # (Q, width, *small) per-key rows, logical order
+        kk = jnp.broadcast_to(q_key[:, None], (q_key.shape[0], width))
+        ss = jnp.broadcast_to(jnp.arange(width, dtype=jnp.int32), kk.shape)
+        return x[cell_index(kk, ss, x.shape[:-2])]
+
+    ts = ring_ts[q_key]                               # (Q, C)
+    lanes = rows(ring_lanes, ring_ts.shape[1])        # (Q, C, L)
+    bstats = rows(bagg_stats, bagg_bucket.shape[1])   # (Q, NB, L, 5)
+    bids = bagg_bucket[q_key]                         # (Q, NB)
     valid = ts != jnp.int32(-2147483648)
     bucket_row = ts // B
 

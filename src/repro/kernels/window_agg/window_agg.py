@@ -5,11 +5,13 @@ needs (sum, count, min, max, sumsq) over several RANGE windows of the
 request key's history.  The skiplist walk of the CPU system becomes, on
 TPU:
 
-* the query's per-key ring row and bucket-aggregate row are selected by a
-  **scalar-prefetched index map** — q_key is prefetched into SMEM before
-  the grid step so the DMA engine can fetch exactly the (1, C, L) ring
-  tile and (1, NB, L, 5) bucket tile for that key into VMEM (no gather op
-  in the kernel body, no host round-trip);
+* the query's per-key ring rows and bucket-aggregate rows are selected by
+  a **scalar-prefetched index map** — q_key is prefetched into SMEM before
+  the grid step so the DMA engine fetches exactly the key's 8-key group
+  of the stored key-minor state ((8, C) ring timestamps, (L, 8, C) ring
+  lanes, (L, 5, 8, NB) bucket stats, (8, NB) bucket ids) into VMEM, and
+  the kernel reads the key's sublane of each (no gather op in the kernel
+  body, no host round-trip);
 * all windows and all lanes are evaluated from that single VMEM-resident
   tile in one grid step — the "parallelize window operations on the same
   table" optimization of the paper, expressed as vector ops over the
@@ -32,7 +34,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["window_stats_pallas", "fold_levels_pallas"]
 
-_TS_EMPTY = -2147483648  # python literal: kernels must not capture device constants
+_TS_EMPTY = -2147483648  # python literal: kernels must not capture arrays
+_KEY_GROUP = 8  # keys per state block: one sublane tile of (K, slot)
 _POS_INF = 3.0e38
 _NEG_INF = -3.0e38
 
@@ -48,12 +51,20 @@ def _window_agg_kernel(
     i = pl.program_id(0)
     ts_q = qts_ref[i]
     B = jnp.int32(bucket_size)
+    r = pl.ds(qkey_ref[i] % _KEY_GROUP, 1)  # the key's sublane
+    L = lanes_ref.shape[0]
 
-    ts = ts_ref[0]          # (C,)
-    g = lanes_ref[0]        # (C, L)
-    bstats = bstats_ref[0]  # (NB, L, 5)
-    bids = bbucket_ref[0]   # (NB,)
-    ql = qlanes_ref[0]      # (L,)
+    ts = ts_ref[r, :][0]                                    # (C,)
+    g = jnp.stack([lanes_ref[l, r, :][0] for l in range(L)], -1)  # (C, L)
+    bstats = jnp.stack(
+        [
+            jnp.stack([bstats_ref[l, j, r, :][0] for j in range(5)], -1)
+            for l in range(L)
+        ],
+        1,
+    )                                                       # (NB, L, 5)
+    bids = bbucket_ref[r, :][0]                             # (NB,)
+    ql = qlanes_ref[0, 0]                                   # (L,)
 
     valid = ts != _TS_EMPTY
     bucket_row = ts // B
@@ -102,8 +113,8 @@ def _window_agg_kernel(
 
 def window_stats_pallas(
     ring_ts: jnp.ndarray,      # (K, C) int32
-    ring_lanes: jnp.ndarray,   # (K, C, L) f32
-    bagg_stats: jnp.ndarray,   # (K, NB, L, 5) f32
+    ring_lanes: jnp.ndarray,   # (L, K, C) f32 (stored layout)
+    bagg_stats: jnp.ndarray,   # (L, 5, K, NB) f32 (stored layout)
     bagg_bucket: jnp.ndarray,  # (K, NB) int32
     q_key: jnp.ndarray,        # (Q,) int32
     q_ts: jnp.ndarray,         # (Q,) int32
@@ -115,10 +126,17 @@ def window_stats_pallas(
 ) -> jnp.ndarray:
     """Returns (Q, NW, L, 5)."""
     K, C = ring_ts.shape
-    L = ring_lanes.shape[-1]
+    L = ring_lanes.shape[0]
     NB = bagg_bucket.shape[1]
     Q = q_key.shape[0]
     NW = len(windows)
+    G = _KEY_GROUP
+    pad = -K % G
+    if pad:  # whole key groups per block (small test stores)
+        ring_ts, ring_lanes, bagg_stats, bagg_bucket = (
+            jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, pad), (0, 0)])
+            for x in (ring_ts, ring_lanes, bagg_stats, bagg_bucket)
+        )
 
     kernel = functools.partial(
         _window_agg_kernel, windows=tuple(windows), bucket_size=bucket_size
@@ -127,11 +145,13 @@ def window_stats_pallas(
         num_scalar_prefetch=2,
         grid=(Q,),
         in_specs=[
-            pl.BlockSpec((1, C), lambda i, qk, qt: (qk[i], 0)),
-            pl.BlockSpec((1, C, L), lambda i, qk, qt: (qk[i], 0, 0)),
-            pl.BlockSpec((1, NB, L, 5), lambda i, qk, qt: (qk[i], 0, 0, 0)),
-            pl.BlockSpec((1, NB), lambda i, qk, qt: (qk[i], 0)),
-            pl.BlockSpec((1, L), lambda i, qk, qt: (i, 0)),
+            pl.BlockSpec((G, C), lambda i, qk, qt: (qk[i] // G, 0)),
+            pl.BlockSpec((L, G, C), lambda i, qk, qt: (0, qk[i] // G, 0)),
+            pl.BlockSpec(
+                (L, 5, G, NB), lambda i, qk, qt: (0, 0, qk[i] // G, 0)
+            ),
+            pl.BlockSpec((G, NB), lambda i, qk, qt: (qk[i] // G, 0)),
+            pl.BlockSpec((1, 1, L), lambda i, qk, qt: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec(
             (1, NW, L, 5), lambda i, qk, qt: (i, 0, 0, 0)
@@ -142,7 +162,8 @@ def window_stats_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Q, NW, L, 5), jnp.float32),
         interpret=interpret,
-    )(q_key, q_ts, ring_ts, ring_lanes, bagg_stats, bagg_bucket, q_lanes)
+    )(q_key, q_ts, ring_ts, ring_lanes, bagg_stats, bagg_bucket,
+      q_lanes.reshape(Q, 1, L))
 
 
 # ---------------------------------------------------------------------------
@@ -311,7 +332,7 @@ def fold_levels_pallas(
             pl.BlockSpec((tile_rows, _FOLD_LANE), lambda t: (t, 0)),
             pl.BlockSpec((tile_rows, _FOLD_LANE), lambda t: (t, 0)),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((levels, R, _FOLD_LANE), x2.dtype),
         scratch_shapes=[
             pltpu.VMEM((tile_rows, _FOLD_LANE), x2.dtype),
@@ -319,7 +340,7 @@ def fold_levels_pallas(
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

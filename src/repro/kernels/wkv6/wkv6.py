@@ -35,9 +35,6 @@ from repro.kernels.wkv6.ref import LOG_W_MIN
 
 __all__ = ["wkv6_pallas", "CHUNK"]
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.4.38; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 CHUNK = 16
 
 
@@ -133,7 +130,7 @@ def wkv6_pallas(
             jax.ShapeDtypeStruct((B, H, D, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

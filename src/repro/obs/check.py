@@ -50,9 +50,11 @@ EXPECTED_METRICS: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
     "export_freshness_seconds": ("histogram", "s", ("view",)),
 }
 
-# populated only when a layout sets a TTL — optional in the golden set
+# populated only when a layout sets a TTL, or a kernel's size cut-over
+# fires on its backend — optional in the golden set
 OPTIONAL_METRICS: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
     "ring_ttl_expired_rows": ("gauge", "1", ("table",)),
+    "kernel_cutover_total": ("counter", "1", ("kernel",)),
 }
 
 EXPECTED_SPAN_NAMES = {

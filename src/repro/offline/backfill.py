@@ -448,9 +448,10 @@ class BackfillSource:
         )
         K = dst_p.ring_keys
 
-        stats = np.array(np.asarray(bagg.stats), np.float32, copy=True)
-        bitmap = np.array(np.asarray(bagg.bitmap), np.int32, copy=True)
-        bucket = np.array(np.asarray(bagg.bucket), np.int64, copy=True)
+        host = pg.bucket_to_host(bagg, int(sharded))
+        stats = np.array(host["stats"], np.float32, copy=True)
+        bitmap = np.array(host["bitmap"], np.int32, copy=True)
+        bucket = np.array(host["bucket"], np.int64, copy=True)
         if not sharded:
             stats, bitmap, bucket = stats[None], bitmap[None], bucket[None]
 
@@ -563,15 +564,10 @@ class BackfillSource:
         if not sharded:
             stats, bitmap, bucket32 = stats[0], bitmap[0], bucket32[0]
             fam_kw = {k: v[0] for k, v in fam_kw.items()}
-        return pg.BucketAgg(
-            stats=jnp.asarray(np.ascontiguousarray(stats)),
-            bitmap=jnp.asarray(np.ascontiguousarray(bitmap)),
-            bucket=jnp.asarray(np.ascontiguousarray(bucket32)),
-            size=bsize,
-            **{
-                k: jnp.asarray(np.ascontiguousarray(v))
-                for k, v in fam_kw.items()
-            },
+        return pg.bucket_from_host(
+            bsize,
+            dict(stats=stats, bitmap=bitmap, bucket=bucket32, **fam_kw),
+            int(sharded),
         )
 
     # -- the splice ---------------------------------------------------------

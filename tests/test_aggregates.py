@@ -34,6 +34,7 @@ from repro.core.aggregates import (
     agg_spec,
 )
 from repro.core.consistency import verify_view
+from repro.core.preagg import bucket_to_host
 from repro.core.expr import UNION_AGGS, Agg
 
 try:
@@ -363,29 +364,30 @@ def test_merge_order_states_through_evolution(num_shards):
 
     # the family state itself matches the cold rebuild wherever observable
     # (fields of absent entries are don't-cares)
-    hb, cb = plane.store.state.bagg, cold.store.state.bagg
-    np.testing.assert_array_equal(np.asarray(hb.seq), np.asarray(cb.seq))
-    has = np.asarray(cb.xhas)
-    np.testing.assert_array_equal(np.asarray(hb.xhas), has)
+    # (compared in the logical per-key layout: (.., K, NB, *small))
+    lead = int(num_shards is not None)
+    hb = bucket_to_host(plane.store.state.bagg, lead)
+    cb = bucket_to_host(cold.store.state.bagg, lead)
+    np.testing.assert_array_equal(hb["seq"], cb["seq"])
+    has = cb["xhas"]
+    np.testing.assert_array_equal(hb["xhas"], has)
     for d in (0, 1):
         m = has[..., d]
         for nm in ("xts", "xpos"):
             np.testing.assert_array_equal(
-                np.asarray(getattr(hb, nm))[..., d][m],
-                np.asarray(getattr(cb, nm))[..., d][m], err_msg=nm,
+                hb[nm][..., d][m], cb[nm][..., d][m], err_msg=nm,
             )
         np.testing.assert_array_equal(
-            np.asarray(hb.xval)[..., d][m], np.asarray(cb.xval)[..., d][m]
+            hb["xval"][..., d][m], cb["xval"][..., d][m]
         )
-    valid = np.asarray(cb.tvalid)
-    np.testing.assert_array_equal(np.asarray(hb.tvalid), valid)
+    valid = cb["tvalid"]
+    np.testing.assert_array_equal(hb["tvalid"], valid)
     for nm in ("tts", "tpos"):
         np.testing.assert_array_equal(
-            np.asarray(getattr(hb, nm))[valid],
-            np.asarray(getattr(cb, nm))[valid], err_msg=nm,
+            hb[nm][valid], cb[nm][valid], err_msg=nm,
         )
-    hv = np.moveaxis(np.asarray(hb.tval), -2, -1)  # (.., T, F) for masking
-    cv = np.moveaxis(np.asarray(cb.tval), -2, -1)
+    hv = np.moveaxis(hb["tval"], -2, -1)  # (.., T, F) for masking
+    cv = np.moveaxis(cb["tval"], -2, -1)
     np.testing.assert_array_equal(hv[valid], cv[valid], err_msg="tval")
 
 

@@ -28,7 +28,6 @@ from repro.core import (
 from repro.core import preagg as pg
 from repro.core import storage as st
 from repro.core.aggregates import row_bitmap
-from repro.kernels.ingest.ingest import _row_bitmap
 from repro.kernels.ingest.ops import fused_ingest
 
 K, C, F, NB, BS = 7, 16, 3, 8, 50
@@ -95,19 +94,22 @@ def test_fused_ingest_all_pad_batch_is_noop():
 
 
 def test_kernel_row_bitmap_matches_library():
-    """The kernel restates aggregates.row_bitmap with python-literal
-    constants (Pallas kernels cannot capture device constants) — pin the
-    bit-exact equality so the hash chains can never drift apart."""
-    rng = np.random.default_rng(2)
-    v = jnp.asarray(
-        np.concatenate([
-            rng.normal(size=500).astype(np.float32),
-            np.array([0.0, -0.0, 1.0, -1.0, 3.0e38, -3.0e38], np.float32),
-        ])
+    """The kernel ORs the library's own ``aggregates.row_bitmap`` lift into
+    its bucket bitmaps — pin it on edge values (signed zeros, ±3e38, the
+    lane identities) with one row per key, so each bucket's bitmap is
+    exactly its row's lift."""
+    v = np.array(
+        [0.0, -0.0, 1.0, -1.0, 3.0e38, -3.0e38, 0.5], np.float32
     )
-    np.testing.assert_array_equal(
-        np.asarray(_row_bitmap(v)), np.asarray(row_bitmap(v))
-    )
+    key = jnp.arange(K, dtype=jnp.int32)
+    vals = jnp.asarray(np.repeat(v[:, None], F, axis=1))
+    ts = jnp.zeros((K,), jnp.int32)
+    out = fused_ingest(*_init_state(), key, ts, vals, bucket_size=BS,
+                       impl="pallas", interpret=True)
+    bitmap = np.asarray(out[STATE_NAMES.index("bbitmap")])   # (F, K, NB)
+    want = np.asarray(row_bitmap(jnp.asarray(v)))
+    for f in range(F):
+        np.testing.assert_array_equal(bitmap[f, :, 0], want)
 
 
 SCHEMA = TableSchema(name="tx", key="uid", ts="ts", numeric=("amount",),

@@ -62,8 +62,32 @@ def test_route_rank_auto_cutoff_is_tpu_only():
     """The auto policy: Pallas only on a TPU backend and only at or
     below the row cutoff — on this (CPU) backend auto resolves to the
     XLA reference for every size."""
-    assert _ROUTE_PALLAS_MAX_ROWS == 1 << 20
+    assert _ROUTE_PALLAS_MAX_ROWS == 1 << 19
     assert jax.default_backend() != "tpu" or pytest.skip("CPU-only check")
+
+
+def test_route_rank_cutover_is_counted(monkeypatch):
+    """On a TPU backend a batch past the kernel's residency cap goes to
+    XLA — and says so in ``kernel_cutover_total`` (the backend is steered
+    here; the XLA path then runs on this CPU)."""
+    import repro.kernels.route.ops as rops
+    from repro.obs import Telemetry, use_telemetry
+
+    tel = Telemetry()
+    n = _ROUTE_PALLAS_MAX_ROWS + 1
+    shard = np.arange(n, dtype=np.int32) % 4
+    with use_telemetry(tel):
+        monkeypatch.setattr(rops.jax, "default_backend", lambda: "tpu")
+        rank, counts = route_rank(jnp.asarray(shard), num_shards=4)
+        monkeypatch.undo()
+    snap = tel.metrics.snapshot()
+    exp_rank, exp_counts = _expected_ranks(shard, 4)
+    assert np.array_equal(np.asarray(rank), exp_rank)
+    assert np.array_equal(np.asarray(counts), exp_counts)
+    cut = snap["kernel_cutover_total"]["series"]
+    assert [s["value"] for s in cut] == [1.0]
+    disp = snap["kernel_dispatch_total"]["series"]
+    assert {(s["labels"]["impl"], s["value"]) for s in disp} == {("xla", 1.0)}
 
 
 @pytest.mark.parametrize("n", [15, 16, 17, 1023, 1024, 1025])
