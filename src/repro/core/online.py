@@ -438,13 +438,11 @@ class OnlineFeatureStore:
         """
         tel = get_telemetry()
         t0 = tel.clock.now()
-        key = jnp.asarray(columns[self.schema.key], jnp.int32)
-        ts = jnp.asarray(columns[self.schema.ts], jnp.int32)
-        lanes = self._lanes(columns)
-
-        import numpy as _np
-
-        ts_h = _np.asarray(ts)
+        with tel.tracer.span("ingest.prepare"):
+            key = jnp.asarray(columns[self.schema.key], jnp.int32)
+            ts = jnp.asarray(columns[self.schema.ts], jnp.int32)
+            lanes = self._lanes(columns)
+            ts_h = np.asarray(ts)
         if ts_h.size == 0:
             return
         with tel.tracer.span(
@@ -460,10 +458,10 @@ class OnlineFeatureStore:
                 # rows are (key, ts)-sorted, so chunk by absolute-bucket
                 # epoch and re-sort each chunk by (key, ts).
                 epoch = b // (self.num_buckets - 1)
-                for e in _np.unique(epoch):
-                    idx = _np.nonzero(epoch == e)[0]
+                for e in np.unique(epoch):
+                    idx = np.nonzero(epoch == e)[0]
                     order = idx[
-                        _np.lexsort((ts_h[idx], _np.asarray(key)[idx]))
+                        np.lexsort((ts_h[idx], np.asarray(key)[idx]))
                     ]
                     self._ingest_padded(key[order], ts[order], lanes[order])
             sp.fence(self.state.ring.cursor)
@@ -1062,17 +1060,18 @@ class OnlineFeatureStore:
         histogram as a routing by-product so callers never re-hash keys.
         """
         tel = get_telemetry()
-        if route_info is not None:
-            n_real = (
-                int(np.asarray(valid, bool).sum())
-                if valid is not None
-                else len(np.asarray(columns[self.schema.key]))
+        with tel.tracer.span("query.prepare"):
+            if route_info is not None:
+                n_real = (
+                    int(np.asarray(valid, bool).sum())
+                    if valid is not None
+                    else len(np.asarray(columns[self.schema.key]))
+                )
+                route_info["shard_counts"] = np.array([n_real], np.int64)
+            key, ts_q, req_lanes, join_keys = self._request_arrays(
+                columns, program
             )
-            route_info["shard_counts"] = np.array([n_real], np.int64)
-        key, ts_q, req_lanes, join_keys = self._request_arrays(
-            columns, program
-        )
-        fn = self._query_fn(mode, program)
+            fn = self._query_fn(mode, program)
         # pad the request to a power-of-two shape bucket (compilation
         # caching: one executable per bucket, not per request size)
         q = int(key.shape[0])
@@ -1105,8 +1104,9 @@ class OnlineFeatureStore:
             else:
                 vals = fn(self.state, key, ts_q, req_lanes, join_keys, key)
             vals = sp.fence(vals)
-        self._note_query(tel, mode, program, m, t_call)
-        return self._finish_query(columns, vals, program)
+        with tel.tracer.span("query.finish"):
+            self._note_query(tel, mode, program, m, t_call)
+            return self._finish_query(columns, vals, program)
 
     def _note_query(self, tel, mode, program, padded_rows, t_call) -> None:
         """Query-side metrics: first-trace compile capture per

@@ -720,13 +720,16 @@ class ShardedOnlineStore(OnlineFeatureStore):
     ):
         """One fused device dispatch under the ``route.device`` span (plus
         the rare overflow re-dispatch at the safe capacity, inside the
-        same span so span count == dispatches per batch stays 1)."""
+        same span so span count == batches stays 1; the re-dispatch is
+        counted in ``route_redispatch_total`` and marks the span
+        ``redispatched``)."""
         B = self._route_bucket(m)
         pname = program.view.name if program is not None else ""
         t_call = tel.clock.now()
         with tel.tracer.span(
             "route.device", kind="device", mode=mode, program=pname,
             rows=q, padded=m, bucket=B, shards=self.num_shards,
+            redispatched=False,
         ) as sp:
             fn = self._route_query_fn(mode, program, B, num_scen)
             vals, scounts, ovf = fn(
@@ -738,6 +741,13 @@ class ShardedOnlineStore(OnlineFeatureStore):
                 # the always-safe bucket == batch size; bit-exactness never
                 # depends on the optimistic guess
                 B = 1 << max(m - 1, 0).bit_length()
+                sp.set(redispatched=True)
+                tel.metrics.counter(
+                    "route_redispatch_total",
+                    "fused route dispatches re-run at full capacity after "
+                    "a shard overflowed its optimistic bucket", "1",
+                    labels=("program",),
+                ).inc(program=pname)
                 fn = self._route_query_fn(mode, program, B, num_scen)
                 vals, scounts, _ = fn(
                     self.state, key_h, ts_h, lanes, jks, scen, valid_h

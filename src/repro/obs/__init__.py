@@ -17,6 +17,7 @@ CI gates (snapshot-schema golden set + instrumentation overhead bound).
 
 from repro.obs.telemetry import (
     DEFAULT_BUCKETS_S,
+    QUEUE_WAIT_BUCKETS_S,
     Clock,
     Counter,
     FakeClock,
@@ -34,6 +35,7 @@ from repro.obs.tracing import Span, Tracer
 
 __all__ = [
     "DEFAULT_BUCKETS_S",
+    "QUEUE_WAIT_BUCKETS_S",
     "Clock",
     "Counter",
     "FakeClock",

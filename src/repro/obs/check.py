@@ -50,16 +50,19 @@ EXPECTED_METRICS: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
     "export_freshness_seconds": ("histogram", "s", ("view",)),
 }
 
-# populated only when a layout sets a TTL, or a kernel's size cut-over
-# fires on its backend — optional in the golden set
+# populated only when a layout sets a TTL, a kernel's size cut-over fires
+# on its backend, or a shard overflows the fused route's optimistic
+# bucket — optional in the golden set
 OPTIONAL_METRICS: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
     "ring_ttl_expired_rows": ("gauge", "1", ("table",)),
     "kernel_cutover_total": ("counter", "1", ("kernel",)),
+    "route_redispatch_total": ("counter", "1", ("program",)),
 }
 
 EXPECTED_SPAN_NAMES = {
-    "request", "query.route", "query.compute", "query.scatter",
-    "route.device", "ingest",
+    "router.pump", "sched.pop", "request", "request.fetch", "request.record",
+    "query.route", "query.compute", "query.scatter", "route.device",
+    "ingest.prepare", "ingest",
     "hot_deploy", "hot_deploy.plan", "hot_deploy.compile",
     "migrate", "migrate.diff", "migrate.carry", "migrate.place",
     "backfill", "backfill.ring", "backfill.bucket", "export",

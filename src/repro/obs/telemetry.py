@@ -17,7 +17,10 @@ report either number.  This module is the measurement substrate:
   Prometheus text-exposition exporter, and a hard per-metric series cap so
   label cardinality cannot grow without bound (the classic metrics-plane
   failure mode).  Histograms keep fixed log-spaced buckets plus a bounded
-  reservoir of recent raw values for tail percentiles.
+  reservoir of recent raw values for tail percentiles; a histogram whose
+  tail is read over a whole run (``queue_wait_seconds``) gets finer
+  bounds (:data:`QUEUE_WAIT_BUCKETS_S`), so a percentile comes from its
+  bucket counts rather than from the reservoir.
 * :class:`Telemetry` — the bundle (clock + metrics + tracer) with a
   process-wide default: ``get_telemetry()`` / ``set_telemetry()`` /
   ``use_telemetry()``.  ``Telemetry(enabled=False)`` is the null plane:
@@ -31,6 +34,7 @@ The metric *catalog* (every name, its labels and unit) is documented in
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import json
 import math
@@ -53,6 +57,7 @@ __all__ = [
     "reset_telemetry",
     "use_telemetry",
     "DEFAULT_BUCKETS_S",
+    "QUEUE_WAIT_BUCKETS_S",
 ]
 
 
@@ -123,6 +128,12 @@ class MetricCardinalityError(RuntimeError):
 DEFAULT_BUCKETS_S: Tuple[float, ...] = (
     1e-5, 3e-5, 1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2,
     1e-1, 3e-1, 1.0, 3.0, 10.0, 30.0,
+)
+
+# queue waits: 10 bounds per decade, 100 µs .. 10 s, so a tail read from
+# the bucket counts is within one bucket (about 26% of its value)
+QUEUE_WAIT_BUCKETS_S: Tuple[float, ...] = tuple(
+    10.0 ** (k / 10) for k in range(-40, 11)
 )
 
 _RESERVOIR = 512  # recent raw values kept per histogram series (tails)
@@ -288,12 +299,9 @@ class Histogram(_MetricBase):
         s.sum += v * n
         if v > s.max:
             s.max = v
-        i = 0
-        for b in self.bounds:
-            if v <= b:
-                break
-            i += 1
-        s.buckets[i] += n
+        # first bound >= v (a value equal to a bound lands in its bucket);
+        # past the last bound, the +inf overflow bucket
+        s.buckets[bisect.bisect_left(self.bounds, v)] += n
         s.recent.append(v)
 
     def observe_array(self, values: Iterable[float], **labels: str) -> None:

@@ -172,27 +172,28 @@ class ShardRouter:
     def pump(
         self, now_us: Optional[int] = None, flush: bool = False
     ) -> Optional[Dict[str, np.ndarray]]:
-        """Serve one coalesced batch; None if nothing is ready yet."""
+        """Serve one coalesced batch; None if nothing is ready yet.
+
+        A pump that pops a batch is the ``router.pump`` span (the pop,
+        the request, the routing histograms); a poll that finds nothing
+        ready records nothing."""
         self._sync_scenarios()
-        batch = self.scheduler.next_batch(now_us=now_us, flush=flush)
-        if batch is None:
+        if not self.scheduler.ready(now_us=now_us, flush=flush):
             return None
-        valid = np.asarray(batch["__valid__"], bool)
-        get_telemetry().metrics.gauge(
-            "batch_occupancy_ratio",
-            "real rows / padded batch rows, last batch", "1",
-            labels=("service",),
-        ).set(
-            float(valid.sum()) / max(len(valid), 1),
-            service=self.service.name,
-        )
+        with get_telemetry().tracer.span("router.pump") as sp:
+            batch = self.scheduler.next_batch(now_us=now_us, flush=flush)
+            valid = np.asarray(batch["__valid__"], bool)
+            sp.set(rows=int(valid.sum()), padded=len(valid))
+            return self._serve(batch, valid)
+
+    def _serve(self, batch: Dict[str, np.ndarray], valid: np.ndarray):
         if self.scenarios is None:
             ri: Dict = {}
             out = self.service.request(
                 batch, ingest=self.ingest, route_info=ri
             )
             self._note_route(ri["shard_counts"], None)
-            return {k: np.asarray(v)[valid] for k, v in out.items()}
+            return {k: v[valid] for k, v in out.items()}
         if getattr(self.service.store, "device_routing", False):
             # device routing: the mixed batch is ONE fused dispatch — the
             # store routes, answers, and histograms every (scenario,
@@ -204,10 +205,7 @@ class ShardRouter:
             scounts = np.asarray(ri["scenario_shard_counts"])
             for i, s in enumerate(ri["scenario_names"]):
                 self._note_route(scounts[i], s)
-            return {
-                s: {k: np.asarray(v) for k, v in cols.items()}
-                for s, cols in results.items()
-            }
+            return results
         # host oracle: partition the popped batch by scenario tag (in
         # submission order within each group) and run each group through
         # its own program — the (scenario, shard) bucketing of the plane.
@@ -235,7 +233,7 @@ class ShardRouter:
             )
             # rows_s was masked by `m`, so every row is a real request
             self._note_route(ri["shard_counts"], s)
-            results[s] = {k: np.asarray(v) for k, v in out.items()}
+            results[s] = out
             groups.append(rows_s)
         if self.ingest:
             schema = self.service.view.schema

@@ -23,7 +23,7 @@ import numpy as np
 
 from repro.core.online import OnlineFeatureStore
 from repro.core.view import FeatureRegistry, FeatureView
-from repro.obs import get_telemetry
+from repro.obs import QUEUE_WAIT_BUCKETS_S, get_telemetry
 
 __all__ = [
     "FeatureService",
@@ -294,7 +294,8 @@ class FeatureService:
             out = self._compute(
                 rows, scenario, valid=valid, route_info=route_info
             )
-            out = {k: np.asarray(v) for k, v in out.items()}
+            with tel.tracer.span("request.fetch"):
+                out = {k: np.asarray(v) for k, v in out.items()}
             if ingest:
                 real = rows
                 if valid is not None:
@@ -308,39 +309,56 @@ class FeatureService:
                         {c: np.asarray(v)[order] for c, v in real.items()}
                     )
         dt = tel.clock.now() - t0
-        # per-request latency = that request's queue wait + batch wall time
-        if wait_us is not None:
-            waits_s = np.asarray(wait_us, np.float64)[:n_rows] / 1e6
-            if valid is not None:
-                waits_s = waits_s[np.asarray(valid, bool)]
+        with tel.tracer.span("request.record"):
+            # per-request latency = that request's queue wait + batch wall
+            if wait_us is not None:
+                waits_s = np.asarray(wait_us, np.float64)[:n_rows] / 1e6
+                if valid is not None:
+                    waits_s = waits_s[np.asarray(valid, bool)]
+                else:
+                    waits_s = waits_s[:n_real]
             else:
-                waits_s = waits_s[:n_real]
-        else:
-            waits_s = np.zeros(n_real, np.float64)
-        req_lat = waits_s + dt
+                waits_s = np.zeros(n_real, np.float64)
+            req_lat = waits_s + dt
+            tel.metrics.counter(
+                "service_requests_total", "requests served", "1",
+                labels=("service", "scenario"),
+            ).inc(n_real, service=self.name, scenario=scenario or "")
+            self._record_batch(
+                tel, req_lat, waits_s if wait_us is not None else None,
+                n_real, n_rows if valid is not None else 0,
+            )
+            self._observe(dt, n_real, scenario, req_lat)
+        return out
+
+    def _record_batch(
+        self,
+        tel,
+        req_lat: np.ndarray,
+        waits_s: Optional[np.ndarray],
+        n_real: int,
+        n_padded: int,
+    ) -> None:
+        """The per-request latency and queue-wait histograms and the batch
+        occupancy gauge of one served batch (``waits_s`` None: the batch
+        carried no wait column; ``n_padded`` 0: no padding mask)."""
         m = tel.metrics
-        m.counter(
-            "service_requests_total", "requests served", "1",
-            labels=("service", "scenario"),
-        ).inc(n_real, service=self.name, scenario=scenario or "")
         m.histogram(
             "request_latency_seconds",
             "per-request latency (queue wait + batch wall)", "s",
             labels=("service",),
         ).observe_array(req_lat, service=self.name)
-        if wait_us is not None and len(waits_s):
+        if waits_s is not None and len(waits_s):
             m.histogram(
                 "queue_wait_seconds", "scheduler queue wait per request",
-                "s", labels=("service",),
+                "s", labels=("service",), bounds=QUEUE_WAIT_BUCKETS_S,
             ).observe_array(waits_s, service=self.name)
-        if valid is not None and n_rows:
+        if n_padded:
             m.gauge(
                 "batch_occupancy_ratio",
                 "real rows / padded batch rows, last batch", "1",
                 labels=("service",),
-            ).set(n_real / n_rows, service=self.name)
-        self._observe(dt, n_real, scenario, req_lat)
-        return out
+            ).set(n_real / n_padded, service=self.name)
 
     def feature_matrix(
         self, rows: Dict[str, np.ndarray], scenario: Optional[str] = None
@@ -499,6 +517,11 @@ class MultiScenarioService(FeatureService):
                 data, tags, mode=self.mode, valid=vmask,
                 route_info=route_info,
             )
+            with tel.tracer.span("request.fetch"):
+                out = {
+                    s: {k: np.asarray(v) for k, v in cols.items()}
+                    for s, cols in out.items()
+                }
             if ingest and n_real:
                 key_c = self.view.schema.key
                 ts_c = self.view.schema.ts
@@ -512,44 +535,32 @@ class MultiScenarioService(FeatureService):
                     )
                     self.store.ingest({c: v[order] for c, v in grp.items()})
         dt = tel.clock.now() - t0
-        if wait_us is not None:
-            waits_s = np.asarray(wait_us, np.float64)[:n_rows] / 1e6
-        else:
-            waits_s = np.zeros(n_rows, np.float64)
-        agg_waits = waits_s[vmask]
-        req_lat = agg_waits + dt
-        m = tel.metrics
-        sreq = m.counter(
-            "service_requests_total", "requests served", "1",
-            labels=("service", "scenario"),
-        )
-        m.histogram(
-            "request_latency_seconds",
-            "per-request latency (queue wait + batch wall)", "s",
-            labels=("service",),
-        ).observe_array(req_lat, service=self.name)
-        if wait_us is not None and len(agg_waits):
-            m.histogram(
-                "queue_wait_seconds", "scheduler queue wait per request",
-                "s", labels=("service",),
-            ).observe_array(agg_waits, service=self.name)
-        if valid is not None and n_rows:
-            m.gauge(
-                "batch_occupancy_ratio",
-                "real rows / padded batch rows, last batch", "1",
-                labels=("service",),
-            ).set(n_real / n_rows, service=self.name)
-        self.stats.observe(dt, n_real)
-        self.stats.observe_requests(req_lat)
-        for s in self.scenarios:
-            msk = vmask & (tags == s)
-            n_s = int(msk.sum())
-            if not n_s:
-                continue
-            sreq.inc(n_s, service=self.name, scenario=s)
-            st = self.scenario_stats[s]
-            st.observe(dt, n_s)
-            st.observe_requests(waits_s[msk] + dt)
+        with tel.tracer.span("request.record"):
+            if wait_us is not None:
+                waits_s = np.asarray(wait_us, np.float64)[:n_rows] / 1e6
+            else:
+                waits_s = np.zeros(n_rows, np.float64)
+            agg_waits = waits_s[vmask]
+            req_lat = agg_waits + dt
+            self._record_batch(
+                tel, req_lat, agg_waits if wait_us is not None else None,
+                n_real, n_rows if valid is not None else 0,
+            )
+            sreq = tel.metrics.counter(
+                "service_requests_total", "requests served", "1",
+                labels=("service", "scenario"),
+            )
+            self.stats.observe(dt, n_real)
+            self.stats.observe_requests(req_lat)
+            for s in self.scenarios:
+                msk = vmask & (tags == s)
+                n_s = int(msk.sum())
+                if not n_s:
+                    continue
+                sreq.inc(n_s, service=self.name, scenario=s)
+                st = self.scenario_stats[s]
+                st.observe(dt, n_s)
+                st.observe_requests(waits_s[msk] + dt)
         return out
 
     def _observe(self, latency_s, n_requests, scenario,
@@ -625,6 +636,24 @@ class BatchScheduler:
             return None
         return self._clock_us(now_us) - self._arrival_us[0]
 
+    def ready(
+        self,
+        now_us: Optional[int] = None,
+        flush: bool = False,
+        max_batch: Optional[int] = None,
+    ) -> bool:
+        """Whether :meth:`next_batch` would pop a batch now: the queue
+        holds a request and, under a ``max_wait_us`` deadline, is full
+        (``max_batch``) or its oldest request has expired — or ``flush``
+        overrides the deadline (shutdown / drain paths)."""
+        if not self.queue:
+            return False
+        if self.max_wait_us is None or flush:
+            return True
+        max_batch = max_batch if max_batch is not None else self.max_batch
+        full = max_batch is not None and len(self.queue) >= max_batch
+        return full or self.oldest_wait_us(now_us) >= self.max_wait_us
+
     def next_batch(
         self,
         max_batch: Optional[int] = None,
@@ -634,18 +663,18 @@ class BatchScheduler:
         """Pop the next padded batch, or None.
 
         None means *empty queue* — or, under a ``max_wait_us`` deadline,
-        *keep coalescing*: the queue is neither full (``max_batch``) nor
-        expired yet.  ``flush=True`` overrides the deadline (shutdown /
-        drain paths).
+        *keep coalescing* (see :meth:`ready`).  The pop itself (row dicts
+        to columns, padding, per-row waits) is the ``sched.pop`` span.
         """
-        if not self.queue:
+        if not self.ready(now_us, flush, max_batch):
             return None
+        with get_telemetry().tracer.span("sched.pop"):
+            return self._pop(max_batch, now_us)
+
+    def _pop(
+        self, max_batch: Optional[int], now_us: Optional[int]
+    ) -> Dict[str, np.ndarray]:
         max_batch = max_batch if max_batch is not None else self.max_batch
-        if self.max_wait_us is not None and not flush:
-            full = max_batch is not None and len(self.queue) >= max_batch
-            expired = self.oldest_wait_us(now_us) >= self.max_wait_us
-            if not (full or expired):
-                return None
         n = len(self.queue)
         if max_batch:
             n = min(n, max_batch)

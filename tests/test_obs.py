@@ -26,8 +26,11 @@ from repro.core import (
 )
 from repro.data.synthetic import FRAUD_SCHEMA
 from repro.obs import (
+    DEFAULT_BUCKETS_S,
+    QUEUE_WAIT_BUCKETS_S,
     FakeClock,
     MetricCardinalityError,
+    MetricRegistry,
     Telemetry,
     use_telemetry,
 )
@@ -103,6 +106,153 @@ def test_disabled_telemetry_records_nothing_but_still_fences():
     assert np.array_equal(out, np.arange(3))
     assert tel.snapshot()["metrics"] == {}
     assert tel.snapshot()["spans"] == []
+
+
+def _one_chip_router(tel, max_wait_us=10_000):
+    view = FeatureView(
+        "tree", FRAUD_SCHEMA,
+        {
+            "s": w_sum(AMT, range_window(600, bucket=64)),
+            "c5": w_count(AMT, rows_window(5)),
+        },
+    )
+    with use_telemetry(tel):
+        svc = FeatureService.build("tree", view, num_keys=32, capacity=64)
+    return ShardRouter(
+        svc, BatchScheduler(buckets=(4,), max_batch=4,
+                            max_wait_us=max_wait_us),
+        ingest=False,
+    )
+
+
+def _names(spans):
+    return [s.name for s in spans]
+
+
+def test_one_pump_span_tree_in_order():
+    """The read path's host phases, as one one-chip pump records them;
+    polls that pop nothing record no span; a write's host preparation
+    precedes its fenced ingest."""
+    clk = FakeClock()
+    tel = Telemetry(clock=clk)
+    router = _one_chip_router(tel)
+    rng = np.random.default_rng(3)
+    with use_telemetry(tel):
+        for i in range(3):
+            router.submit(_row(rng, 1_000 + i), now_us=0)
+        # neither full (4) nor expired (10 ms): keep coalescing
+        assert router.pump(now_us=5_000) is None
+        assert router.pump(now_us=9_999) is None
+        assert tel.tracer.roots() == []
+        clk.advance(0.010)
+        out = router.pump(now_us=10_000)
+        assert len(out["s"]) == 3
+        assert router.pump(now_us=20_000) is None  # queue empty
+    (pump,) = tel.tracer.roots()
+    assert pump.name == "router.pump"
+    assert pump.attrs == {"rows": 3, "padded": 4}
+    assert tel.metrics.metrics()["queue_wait_seconds"].bounds == (
+        QUEUE_WAIT_BUCKETS_S
+    )
+    assert _names(pump.children) == ["sched.pop", "request", "request.record"]
+    (request,) = pump.find("request")
+    assert _names(request.children) == [
+        "query.prepare", "query.compute", "query.finish", "request.fetch",
+    ]
+    (compute,) = pump.find("query.compute")
+    assert compute.kind == "device" and compute.fenced
+    host = [s for s in pump.children + request.children if s is not compute]
+    assert all(s.kind == "host" for s in host)
+    with use_telemetry(tel):
+        cols = {k: np.asarray([v]) for k, v in _row(rng, 2_000).items()}
+        router.service.store.ingest(cols)
+    assert _names(tel.tracer.roots()[1:]) == ["ingest.prepare", "ingest"]
+
+
+def test_spans_reach_the_profiler_trace(tmp_path):
+    """Every span is also a profiler annotation: in a CPU trace of two
+    pumps under an outer annotation, each ``router.pump`` lies inside the
+    outer one and each ``query.compute`` inside a ``router.pump``, on one
+    host timeline."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    tel = Telemetry()
+    router = _one_chip_router(tel, max_wait_us=None)
+    rng = np.random.default_rng(4)
+    with use_telemetry(tel):
+        router.submit(_row(rng, 1_000), now_us=0)
+        router.pump(now_us=0)  # compile outside the trace
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with TraceAnnotation("outer"):
+                for i in range(2):
+                    router.submit(_row(rng, 1_001 + i), now_us=0)
+                    assert router.pump(now_us=0) is not None
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    lines = [
+        [(e.name, e.start_ns, e.start_ns + e.duration_ns) for e in ln.events]
+        for plane in ProfileData.from_file(path).planes
+        for ln in plane.lines
+    ]
+    (line,) = [ln for ln in lines if any(e[0] == "outer" for e in ln)]
+
+    def named(name):
+        return [(a, b) for n, a, b in line if n == name]
+
+    (outer,) = named("outer")
+    pumps = named("router.pump")
+    computes = named("query.compute")
+    assert len(pumps) == 2 and len(computes) == 2
+    for a, b in pumps:
+        assert outer[0] <= a <= b <= outer[1]
+    for a, b in computes:
+        assert any(pa <= a <= b <= pb for pa, pb in pumps)
+
+
+def _scan_index(bounds, v):
+    """The bucket a linear scan picks: the first bound >= v, else the
+    overflow bucket."""
+    i = 0
+    for b in bounds:
+        if v <= b:
+            break
+        i += 1
+    return i
+
+
+@pytest.mark.parametrize(
+    "bounds", [DEFAULT_BUCKETS_S, QUEUE_WAIT_BUCKETS_S],
+    ids=["default", "queue_wait"],
+)
+def test_histogram_bucket_matches_the_linear_scan(bounds):
+    edges = list(bounds)
+    values = (
+        edges
+        + [np.nextafter(b, np.inf) for b in edges]
+        + [np.nextafter(b, -np.inf) for b in edges]
+        + [(a + b) / 2 for a, b in zip(edges, edges[1:])]
+        + [-1.0, 0.0, edges[0] / 2, edges[-1] * 1.5, edges[-1] * 1e6]
+    )
+    for v in values:
+        h = MetricRegistry().histogram("h_seconds", bounds=bounds)
+        h.observe(float(v))
+        counts = [c for _, c in h.snapshot()["series"][0]["buckets"]]
+        assert counts.index(1.0) == _scan_index(bounds, float(v)), v
+        assert sum(counts) == 1.0
+
+
+def test_queue_wait_histogram_has_ten_bounds_per_decade():
+    b = np.asarray(QUEUE_WAIT_BUCKETS_S)
+    assert len(b) == 51
+    assert b[0] == pytest.approx(1e-4) and b[-1] == pytest.approx(10.0)
+    np.testing.assert_allclose(b[1:] / b[:-1], 10 ** 0.1)
 
 
 def test_unified_clock_spans_scheduler_and_registry():

@@ -235,3 +235,43 @@ def test_compile_budget_under_generated_view_diversity():
     assert by_group, "fused path never compiled"
     for group, buckets in by_group.items():
         assert len(buckets) <= 2, (group, buckets)
+
+
+def test_overflow_redispatch_is_counted():
+    """A one-key batch puts every row on one of 4 shards, past the
+    optimistic bucket: the fused route re-dispatches once, counted in
+    ``route_redispatch_total`` and marked on its ``route.device`` span;
+    a batch spread over the shards re-dispatches nothing."""
+    from repro.obs import Telemetry, use_telemetry
+
+    tel = Telemetry()
+    with use_telemetry(tel):
+        store = _edge_store(num_keys=256, num_shards=4)
+        n = 64
+        assert store._route_bucket(n) == 32
+
+        def req(keys):
+            return dict(
+                entity=keys.astype(np.int32),
+                ts=np.full(n, 3500, np.int32),
+                amount=np.ones(n, np.float32),
+                quantity=np.ones(n, np.float32),
+                score=np.zeros(n, np.float32),
+                item=np.zeros(n, np.int32),
+            )
+
+        spread = np.arange(n)
+        assert np.bincount(np.asarray(store.shard_of(spread))).max() <= 32
+        store.query(req(spread))
+        assert "route_redispatch_total" not in tel.metrics.snapshot()
+        store.query(req(np.full(n, 7)))
+    counter = tel.metrics.snapshot()["route_redispatch_total"]["series"]
+    assert [(s["labels"], s["value"]) for s in counter] == [
+        ({"program": ""}, 1.0)
+    ]
+    marks = [
+        s.attrs["redispatched"]
+        for r in tel.tracer.roots()
+        for s in r.find("route.device")
+    ]
+    assert marks == [False, True]
