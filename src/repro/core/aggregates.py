@@ -499,30 +499,29 @@ class AggSpec:
 
     def fold_buckets(
         self,
-        stats: jnp.ndarray,   # (Q, M, NUM_STATS) gathered bucket stat rows
-        bitmap: jnp.ndarray,  # (Q, M) gathered bucket bitmaps
+        stats: Dict[str, jnp.ndarray],  # {lane: (Q, M)} bucket stat rows
+        bitmap: jnp.ndarray,  # (Q, M) bucket bitmaps (bitmap specs)
         ok: jnp.ndarray,      # (Q, M) bucket-valid mask
-        ext: Dict[str, jnp.ndarray] = None,  # gathered extreme/tail arrays
+        ext: Dict[str, jnp.ndarray] = None,  # persisted extreme/tail rows
         rank: jnp.ndarray = None,            # stream rank to stamp on states
     ) -> Dict[str, jnp.ndarray]:
         """Fold pre-aggregated bucket states (bucket_composable specs only).
 
         The bucket store persists full stat vectors and bitmaps — i.e. the
         lifted-and-combined states of this algebra — so composing a long
-        window is just more ``combine``.  Extreme/tail specs read their
+        window is just more ``combine``.  Lane specs read ``stats[lane]``
+        for each lane they select.  Extreme/tail specs read their
         persisted merge-order states from ``ext`` instead: for extreme,
-        ``{ts, pos, val, has}`` each (Q, M, 2) with the trailing axis the
-        direction (0 = oldest, 1 = newest); for tail, ``{ts, pos, val,
-        valid}`` each (Q, M, T) newest-first per bucket.  Buckets cover
-        disjoint ts ranges, so cross-bucket ties never happen and the
-        stored per-key arrival ``pos`` only ever breaks ties within one
-        bucket — where it is exact.
+        ``{ts, pos, val, has}`` each (Q, M), the winner in this spec's
+        direction; for tail, ``{ts, pos, val, valid}`` each (Q, M, T)
+        newest-first per bucket.  Buckets cover disjoint ts ranges, so
+        cross-bucket ties never happen and the stored per-key arrival
+        ``pos`` only ever breaks ties within one bucket — where it is
+        exact.
         """
         if self.state == "lanes":
             return {
-                l: lane_masked_reduce(
-                    l, stats[..., LANES.index(l)], ok, 1
-                )
+                l: lane_masked_reduce(l, stats[l], ok, 1)
                 for l in self.lanes
             }
         if self.state == "bitmap":
@@ -535,10 +534,8 @@ class AggSpec:
                 "arrays (layout planned without them)"
             )
         if self.state == "extreme":
-            d = 1 if self.newest else 0
-            ts, pos = ext["ts"][..., d], ext["pos"][..., d]
-            val = ext["val"][..., d]
-            has = ext["has"][..., d] & ok
+            ts, pos, val = ext["ts"], ext["pos"], ext["val"]
+            has = ext["has"] & ok
             if self.newest:
                 ts_m = jnp.where(has, ts, _TS_MIN)
                 best_ts = jnp.max(ts_m, axis=1)

@@ -72,7 +72,7 @@ import numpy as np
 
 from repro.core import preagg as pg
 from repro.core import storage as st
-from repro.core.aggregates import agg_spec
+from repro.core.aggregates import LANES, agg_spec
 from repro.core.expr import (
     Expr,
     WindowAgg,
@@ -655,7 +655,7 @@ class OnlineFeatureStore:
             if gk not in gathers:
                 gathers[gk] = st.ring_gather(state.sec[ring_ix], jk)
             ts_t, lanes_t, valid_t = gathers[gk]
-            g = lanes_t[..., self._ring_lane_of[ring_ix][lj.arg.key]]
+            g = lanes_t[self._ring_lane_of[ring_ix][lj.arg.key]]
             m = valid_t & (ts_t <= ts_q[:, None])
             ts_m = jnp.where(m, ts_t, _TS_MIN)
             mx = jnp.max(ts_m, axis=1)
@@ -670,13 +670,14 @@ class OnlineFeatureStore:
     # -- the one query path ---------------------------------------------------
 
     def _preagg_parts(self, wa, state, key, ts_q, ts_buf, valid, lane):
-        """Raw boundary-row mask + gathered middle-bucket states for a RANGE
-        window on the pre-agg path.
+        """Raw boundary-row mask + middle-bucket states for a RANGE window
+        on the pre-agg path.
 
         The window decomposes into [raw head rows in the oldest partial
         bucket] + [full buckets strictly inside] + [raw tail rows in the
         request's bucket]; middles come back as persisted aggregate states
-        ready for ``AggSpec.fold_buckets``.
+        ready for ``AggSpec.fold_buckets``, read as whole per-key rows of
+        only the planes this wagg's spec folds.
         """
         B = jnp.int32(self.bucket_size)
         nb = self.num_buckets
@@ -694,42 +695,57 @@ class OnlineFeatureStore:
         tail_m = valid & not_future & in_lo & (bucket_buf == b_q[:, None])
         raw = head_m | tail_m
 
-        # middle full buckets b_lo+1 .. b_q-1, selected by membership
+        # middle full buckets b_lo+1 .. b_q-1: each plane's (K, NB) row at
+        # the key, rotated so slot 0 holds bucket b_lo+1 (ids map to slots
+        # mod NB), then the first M slots
         M = self._max_mid(wa)
         mids = b_lo[:, None] + 1 + jnp.arange(M, dtype=jnp.int32)[None, :]
         mvalid = mids < b_q[:, None]
-        slots = mids % nb
-        kk = jnp.broadcast_to(key[:, None], slots.shape)
+        shift = (b_lo + 1) % nb
         bagg = state.bagg
 
-        def cells(x, lane_axis=None):
-            # scalar gathers from the stored (*small, K, NB) layout; the
-            # lane axis (when the array has one) is pinned to this wagg's
-            pin = None if lane_axis is None else {lane_axis: lane}
-            return x[st.cell_index(kk, slots, x.shape[:-2], pin)]
+        def mid(rows):
+            # (..., Q, NB) rows -> (..., Q, M) middles, oldest first
+            return st.rotate_rows(rows, shift)[..., :M]
 
-        stored = bagg.bucket[kk, slots]
-        ok = mvalid & (stored == mids)
-        ms = cells(bagg.stats, 0)    # (Q, M, NUM_STATS)
-        mb = cells(bagg.bitmap, 0)   # (Q, M)
-        # merge-order families gather their persisted states alongside
-        # (only for the spec that reads them — the arrays exist whenever
-        # the layout planned them, asserted by the caller's family gate)
-        ext = None
+        def mid_rows(x, *pin):
+            # the middles of the (K, NB) plane at small position ``pin``
+            return mid(x[(*pin, key)])
+
+        ok = mvalid & (mid_rows(bagg.bucket) == mids)
         spec = agg_spec(wa.agg)
-        if spec.state == "extreme":
+        ms, mb, ext = {}, None, None
+        if spec.state == "lanes":
+            ms = {
+                l: mid_rows(bagg.stats, lane, LANES.index(l))
+                for l in spec.lanes
+            }
+        elif spec.state == "bitmap":
+            mb = mid_rows(bagg.bitmap, lane)
+        # merge-order families read their persisted states (the arrays
+        # exist whenever the layout planned them, asserted by the caller's
+        # family gate): extreme at this spec's direction (0 = oldest,
+        # 1 = newest), tail as (Q, M, T) newest-first per bucket
+        elif spec.state == "extreme":
+            d = 1 if spec.newest else 0
             ext = {
-                "ts": cells(bagg.xts),          # (Q, M, 2)
-                "pos": cells(bagg.xpos),
-                "val": cells(bagg.xval, 0),
-                "has": cells(bagg.xhas),
+                "ts": mid_rows(bagg.xts, d),
+                "pos": mid_rows(bagg.xpos, d),
+                "val": mid_rows(bagg.xval, lane, d),
+                "has": mid_rows(bagg.xhas, d),
             }
         elif spec.state == "tail":
+            def tail_rows(x, *pin):
+                rows = jnp.stack(
+                    [x[(*pin, t, key)] for t in range(x.shape[-3])]
+                )
+                return jnp.moveaxis(mid(rows), 0, -1)
+
             ext = {
-                "ts": cells(bagg.tts),          # (Q, M, T)
-                "pos": cells(bagg.tpos),
-                "val": cells(bagg.tval, 0),
-                "valid": cells(bagg.tvalid),
+                "ts": tail_rows(bagg.tts),
+                "pos": tail_rows(bagg.tpos),
+                "val": tail_rows(bagg.tval, lane),
+                "valid": tail_rows(bagg.tvalid),
             }
         return raw, ms, mb, ok, ext
 
@@ -770,7 +786,7 @@ class OnlineFeatureStore:
             wa = self.waggs[wk]
             spec = agg_spec(wa.agg)
             lane = self._lane_of[wa.arg.key]
-            g = lanes_buf[..., lane]
+            g = lanes_buf[lane]
             r = req_lanes[:, req_lane_of[wa.arg.key]]
             # merge-order coordinate of the request row: primary stream
             # (rank = len(union), matching join.merge_streams), newer than
@@ -811,7 +827,7 @@ class OnlineFeatureStore:
                 ts_t, lanes_t, valid_t = sec_gathers[t]
                 ring_ix = self._union_ring_ix[t]
                 lane_ix = self._ring_lane_of[ring_ix]
-                g_t = lanes_t[..., lane_ix[wa.arg.key]]
+                g_t = lanes_t[lane_ix[wa.arg.key]]
                 # union rows expire on their *own* ring's TTL when the
                 # layout sets one (per-table knob); else the primary's
                 m_t = self._window_mask(

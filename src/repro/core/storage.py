@@ -38,7 +38,8 @@ from repro.core.hashing import fold_hash
 
 __all__ = [
     "TableSchema", "Database", "RowCodec", "RingStore",
-    "ring_init", "ring_ingest", "cell_index", "to_logical", "to_stored",
+    "ring_init", "ring_ingest", "ring_gather", "cell_index", "rotate_rows",
+    "to_logical", "to_stored",
 ]
 
 
@@ -52,9 +53,15 @@ __all__ = [
 # then the per-key slot axis (ring slot C or bucket slot NB) -- are the two
 # minor ones.  A TPU tiles the two minor axes of an array as (8 sublanes x
 # 128 lanes), so (K, slot) tiles densely where a minor F=2 or NUM_STATS=5
-# axis would pad 64x or 25x.  Device code addresses single cells with
-# scalar indices (``cell_index``) and never slices a window over a small
-# leading axis: XLA answers such a window by relaying out the whole array.
+# axis would pad 64x or 25x.
+#
+# Device reads take whole (K, slot) rows at one pinned small-axis position
+# (``x[f, s, keys]``: one contiguous SLOT-wide slice per queried key) and
+# pick the slots they need on-chip with vector ops (``rotate_rows``).  A
+# gather whose slice spans a small axis (``x[:, :, keys]``,
+# ``jnp.take(x[f], keys, axis=1)``) makes XLA relayout the whole state
+# first: a state-sized copy per call.
+# Ingest scatters address single cells with scalar indices (``cell_index``).
 # Host code (migration, backfill) works in the logical per-key layout
 # (K, slot, *small) and converts at its edges with ``to_logical`` /
 # ``to_stored``; ``lead`` counts leading batch axes (the shard axis).
@@ -101,6 +108,21 @@ def cell_index(k, s, small: Tuple[int, ...], pin: Optional[Dict[int, int]] = Non
     out.append(jnp.broadcast_to(k.reshape(b + tail), shape))
     out.append(jnp.broadcast_to(s.reshape(b + tail), shape))
     return tuple(out)
+
+
+def rotate_rows(x: jnp.ndarray, shift: jnp.ndarray) -> jnp.ndarray:
+    """``out[..., q, j] = x[..., q, (j + shift[q]) % N]`` for ``x`` of shape
+    ``(..., Q, N)`` and ``shift`` (Q,) in [0, N).
+
+    A barrel shifter: one static roll per bit of the shift, each taken
+    where that bit is set -- vector selects only, no gather.
+    """
+    n = x.shape[-1]
+    for b in range(max(n - 1, 0).bit_length()):
+        step = 1 << b
+        rolled = jnp.concatenate([x[..., step:], x[..., :step]], axis=-1)
+        x = jnp.where(((shift >> b) & 1)[..., None] == 1, rolled, x)
+    return x
 
 
 @dataclasses.dataclass(frozen=True)
@@ -269,18 +291,21 @@ def ring_ingest(
 def ring_gather(
     store: RingStore, keys: jnp.ndarray
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Gather each queried key's ring unrolled oldest->newest.
+    """Read each queried key's ring unrolled oldest->newest.
 
-    Returns (ts (Q, C), vals (Q, C, F), valid (Q, C)).
+    One row read per plane (timestamps, then each lane), each row rotated
+    to start at the key's oldest slot ``cursor % C``.  Returns
+    (ts (Q, C), vals (F, Q, C), valid (Q, C)).
     """
     cap = store.capacity
     cur = store.cursor[keys]  # (Q,)
     # slot order oldest..newest: cursor - C .. cursor - 1  (mod C)
     offs = jnp.arange(cap, dtype=jnp.int32)[None, :]
-    slots = (cur[:, None] - cap + offs) % cap
     age_rank = cur[:, None] - cap + offs  # absolute row index; <0 => never written
     valid = age_rank >= 0
-    kk = jnp.broadcast_to(keys[:, None], slots.shape)
-    ts = store.ts[kk, slots]
-    vals = store.vals[cell_index(kk, slots, (store.width,))]
+    shift = cur % cap
+    ts = rotate_rows(store.ts[keys], shift)
+    vals = rotate_rows(
+        jnp.stack([store.vals[f, keys] for f in range(store.width)]), shift
+    )
     return ts, vals, valid

@@ -113,25 +113,67 @@ def test_ingest_program_fits_at_real_key_count(one_chip, impl):
         assert ma.temp_size_in_bytes < 0.01 * state_b
 
 
+def _hlo_dims(hlo: str) -> dict:
+    """Result dims of every array-valued HLO instruction, by name."""
+    return {
+        m.group(1): tuple(int(d) for d in m.group(2).split(",") if d)
+        for m in re.finditer(r"%([\w.\-]+) = \w+\[([\d,]*)\]", hlo)
+    }
+
+
+def _cell_gathers_of_state(hlo: str) -> list:
+    """Gathers that read a (..., K, C) or (..., K, NB) state operand one
+    cell at a time: slice size 1 in its minor (slot) axis.  The (K,)
+    cursor has no slot axis and is not one."""
+    dims = _hlo_dims(hlo)
+    out = []
+    for m in re.finditer(
+        r"gather\(%([\w.\-]+), [^\n]*?slice_sizes=\{([\d,]*)\}", hlo
+    ):
+        d = dims.get(m.group(1), ())
+        sizes = [int(x) for x in m.group(2).split(",") if x]
+        if len(d) >= 2 and d[-2] == K and d[-1] in (C, NB) and sizes[-1] == 1:
+            out.append(m.group(0))
+    return out
+
+
+def _relayouts_of_state(hlo: str) -> list:
+    """copy / transpose instructions whose result is as large as one
+    (K, C) state plane: a relayout of per-key state."""
+    out = []
+    for line in hlo.splitlines():
+        m = re.search(
+            r"= \w+\[([\d,]*)\]\S* (copy|copy-start|transpose)\(", line
+        )
+        if m and int(np.prod([int(d) for d in m.group(1).split(",") if d])) \
+                >= K * C:
+            out.append(line.strip())
+    return out
+
+
 def test_preagg_query_program_fits_at_real_key_count(one_chip):
     """The store's pre-aggregated query program, traced by a store of the
     same layout at a small key count and compiled against the 131,072-key
-    state: temporaries stay within the state's size."""
+    state: it reads whole per-key rows, so it needs almost no temporaries,
+    never relayouts the state, and has no cell-at-a-time gather of it."""
     st = _fraud_state()
     store = OnlineFeatureStore(
         fraud_view(), num_keys=64, capacity=C, num_buckets=NB,
         bucket_size=BS,
     )
-    q = 256
+    q = 512
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     compiled = store._query_preagg_fn.lower(
         _abstract(st, one_chip), sd((q,), jnp.int32), sd((q,), jnp.int32),
         sd((q, store.num_lanes), jnp.float32), (), sd((q,), jnp.int32),
     ).compile()
     ma = compiled.memory_analysis()
-    state_b = _nbytes(st)
-    assert ma.temp_size_in_bytes <= state_b
+    assert ma.temp_size_in_bytes < 64 * 2**20
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes <= HBM_LIMIT
+    hlo = compiled.as_text()
+    assert "gather(" in hlo
+    assert _relayouts_of_state(hlo) == []
+    assert _cell_gathers_of_state(hlo) == []
 
 
 @pytest.mark.parametrize("n", [4096, _ROUTE_PALLAS_MAX_ROWS])
@@ -210,7 +252,8 @@ def test_sharded_ingest_keeps_state_on_its_shard(mesh4):
 
 def test_sharded_route_query_keeps_state_on_its_shard(mesh4, monkeypatch):
     """The fused route+query program (route kernel + vmapped per-shard
-    query) compiles on a 4-chip mesh with no all-gather of state."""
+    query) compiles on a 4-chip mesh with no all-gather of state, and its
+    per-shard query reads whole rows as the one-chip program does."""
     import repro.kernels.route.ops as rops
 
     store, state = _sharded_store(mesh4)
@@ -231,3 +274,5 @@ def test_sharded_route_query_keeps_state_on_its_shard(mesh4, monkeypatch):
     hlo = lowered.compile().as_text()
     assert "tpu_custom_call" in hlo
     assert _gathers_of_state(hlo, state) == []
+    assert _relayouts_of_state(hlo) == []
+    assert _cell_gathers_of_state(hlo) == []
