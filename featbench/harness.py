@@ -198,7 +198,8 @@ def run_window(dep: Deployment, sched: traffic.Schedule, seconds: float,
                poll_s: float, annotate: Callable,
                at_end: Callable[[], None]) -> Window:
     """Drive the open loop for ``seconds``, call ``at_end``, then drain
-    what came due in the window."""
+    what came due in the window for at most ``DRAIN_LIMIT_S`` after
+    ``at_end`` returns."""
     cfg = dep.cfg
     router = dep.router()
     features = list(dep.svc.view.features)
@@ -287,9 +288,12 @@ def run_window(dep: Deployment, sched: traffic.Schedule, seconds: float,
                 time.sleep(gap - 1e-4)
     win.end = clock() - t0
     at_end()
-    # what came due in the window and was not yet served or ingested
+    # what came due in the window and was not yet served or ingested, for
+    # DRAIN_LIMIT_S from the return of at_end (a profiler's stop can take
+    # most of a minute)
     submit_until(seconds)
-    while clock() - t0 < seconds + DRAIN_LIMIT_S:
+    closed = clock()
+    while clock() - closed < DRAIN_LIMIT_S:
         now = clock() - t0
         deliver(seconds)
         if not serve(now, flush=True):

@@ -164,6 +164,40 @@ def test_broken_timed_path_is_not_correct(cell, cls, attr, wrap,
     assert not r["correct"], r["checks"]
 
 
+def test_the_drain_runs_its_limit_from_the_close(monkeypatch):
+    """What came due in the window is drained for the drain limit after
+    ``at_end`` returns, however long ``at_end`` took (a profiler's stop):
+    here it sleeps past a shortened limit, and with a 400-ms poll and a
+    300-ms scheduler wait some writes and reads are still pending at the
+    close."""
+    import jax
+
+    orig = harness.run_window
+    pending = []
+
+    def slow_close(dep, sched, seconds, poll_s, annotate, at_end):
+        def late():
+            at_end()
+            time.sleep(1.5)
+        win = orig(dep, sched, seconds, poll_s, annotate, late)
+        # reads and writes not yet served or ingested at the close
+        pending.append(int(np.sum(~(win.read_pump <= win.end))) + sum(
+            int(np.sum(~(d <= win.end))) for d in win.write_done.values()))
+        return win
+
+    monkeypatch.setattr(harness, "run_window", slow_close)
+    monkeypatch.setattr(harness, "DRAIN_LIMIT_S", 1.0)
+    cell = CELLS["fraud_cards.read95"]
+    cfg = tiny(cell)
+    cfg["scheduler"] = dict(cfg["scheduler"], max_wait_us=300_000)
+    r = harness.run(
+        cell, 13, 1.0, False, devices=jax.devices()[:1],
+        t_proc=time.perf_counter(), cfg_override=cfg,
+        mix_override={"rate_ops_per_s": 300, "poll_interval_ms": 400})
+    assert pending[0] > 0
+    assert r["failed"] == 0 and r["correct"], r["checks"]
+
+
 def test_same_seed_same_inputs_and_every_seed_the_same_counts():
     import traffic
 

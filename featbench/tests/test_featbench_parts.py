@@ -126,6 +126,62 @@ def test_reducer_on_a_recorded_chip_trace():
     assert sec == pytest.approx(want["kernel_s"], rel=1e-4)
 
 
+def _ingest_ctx(trace):
+    """A traced run's context for the fused ingest reader: one 64-row
+    ingest batch of the primary table over 40 (key, bucket) pairs."""
+    return {"trace": trace, "lanes": {"transactions": 2},
+            "win": NS(end=1.0, ingests=[("transactions", 0.1, 0.2, 64, 40,
+                                         40)]),
+            "peaks": harness.load_peaks("TPU v5 lite")}
+
+
+def test_fused_ingest_roofline_on_the_recorded_chip_trace():
+    """On the recorded one-chip trace the reader times the kernel the
+    profiler's own export counts (``kernel_s``, ``kernel_runs``)."""
+    from jax.profiler import ProfileData
+
+    with open(os.path.join(RECORDED, "expected.json")) as f:
+        want = json.load(f)
+    with gzip.open(os.path.join(RECORDED, "read95_v5e.xplane.pb.gz")) as f:
+        r = xplane.reduce(ProfileData.from_serialized_xspace(f.read()), [0])
+    reader = harness.load_module(
+        os.path.join(FB, "metrics", "fused_ingest_roofline.py"), "m_fir")
+    _, n = xplane.seconds_matching(r["module_ops"][reader.PROGRAM],
+                                   reader.KERNEL)
+    assert n == want["kernel_runs"]
+    need = roofline.fused_ingest_bytes(64, 40, 2)
+    assert reader.read(_ingest_ctx(r)) == pytest.approx(
+        100 * need / 819e9 / want["kernel_s"], rel=1e-4)
+
+
+def test_fused_ingest_roofline_finds_the_four_chip_kernel():
+    """On four chips XLA names the kernel's op after the sharded store's
+    vmap (``%vmap__.1``): the reader finds it as the Mosaic call inside
+    ``jit__ingest_pure`` on each chip, and not the route kernel's Mosaic
+    call inside the route-and-query program."""
+    mosaic = 'custom_call_target="tpu_custom_call"'
+
+    def chip(i):
+        return NS(name=f"/device:TPU:{i}", lines=[
+            NS(name="XLA Modules", events=[
+                _ev("jit__ingest_pure(7)", 100, 100),
+                _ev("jit__unknown(9)", 300, 100)]),
+            NS(name="XLA Ops", events=[
+                _ev("%fusion.2 = s32[1,64]{1,0} fusion(...)", 110, 10),
+                _ev(f"%vmap__.1 = (s32[1,8,256]{{2,1,0}}, ...) "
+                    f"custom-call(...), {mosaic}", 130, 40 + i),
+                _ev(f"%route_rank_pallas.1 = s32[512]{{0}} "
+                    f"custom-call(...), {mosaic}", 310, 50)])])
+
+    r = xplane.reduce(NS(planes=[chip(i) for i in range(4)]), range(4))
+    reader = harness.load_module(
+        os.path.join(FB, "metrics", "fused_ingest_roofline.py"), "m_fir4")
+    kernel_s = (40 + 41 + 42 + 43) * 1e-9
+    need = roofline.fused_ingest_bytes(64, 40, 2)
+    assert reader.read(_ingest_ctx(r)) == pytest.approx(
+        100 * need / 819e9 / kernel_s)
+
+
 # -- the reference ---------------------------------------------------------------
 
 
@@ -265,7 +321,7 @@ def _merge(a, b):
 def _ctx(cell, parts):
     """A traced run's context for ``cell`` holding ``parts`` of cases."""
     data = _merge({"telemetry": {},
-                   "trace": {"ops": {}, "modules": {}},
+                   "trace": {"ops": {}, "modules": {}, "module_ops": {}},
                    "win": {"pumps": [], "ingests": [], "read_due": [],
                            "read_pump": []},
                    "lanes": {}}, parts)

@@ -13,6 +13,8 @@ on the clock the device events were aligned to.
 * ops / modules: device seconds, counts and the first event's string
   stats per op name and per executable (the module's name without its
   ``(...)`` suffix), summed over chips.
+* module_ops: the same per op name within each executable; an op belongs
+  to the run of a module on its chip that holds the op's start.
 * breakdown: the ten ops that took most device time, and the ten longest
   idle gaps of the first chip, each named by the harness span the host was
   in at the gap's middle (``idle`` where it was in none: waiting for
@@ -24,6 +26,7 @@ from __future__ import annotations
 import glob
 import os
 import re
+from bisect import bisect_right
 from collections import defaultdict
 from typing import Dict, List, Sequence, Tuple
 
@@ -81,6 +84,8 @@ def reduce(profile, device_ids: Sequence[int]) -> dict:
     busy_iv: Dict[int, List[Tuple[int, int]]] = {}
     ops: Dict[str, list] = defaultdict(lambda: [0.0, 0, ""])
     modules: Dict[str, list] = defaultdict(lambda: [0.0, 0, ""])
+    module_ops: Dict[str, Dict[str, list]] = defaultdict(
+        lambda: defaultdict(lambda: [0.0, 0, ""]))
     spans: List[Tuple[int, int, str]] = []
     for plane in profile.planes:
         m = _DEVICE.match(plane.name)
@@ -93,15 +98,26 @@ def reduce(profile, device_ids: Sequence[int]) -> dict:
         dev = int(m.group(1))
         if dev not in want:
             continue
+        runs: List[Tuple[int, int, str]] = []
+        dev_ops = []
         for line in plane.lines:
             if line.name == "XLA Ops":
                 iv = busy_iv.setdefault(dev, [])
                 for ev, name, t, d in _events(line):
                     iv.append((t, t + d))
                     _add(ops[name], ev, d)
+                    dev_ops.append((ev, name, t, d))
             elif line.name == "XLA Modules":
                 for ev, name, t, d in _events(line):
-                    _add(modules[name.split("(")[0]], ev, d)
+                    mod = name.split("(")[0]
+                    _add(modules[mod], ev, d)
+                    runs.append((t, t + d, mod))
+        runs.sort()
+        starts = [r[0] for r in runs]
+        for ev, name, t, d in dev_ops:
+            i = bisect_right(starts, t) - 1
+            if i >= 0 and t < runs[i][1]:
+                _add(module_ops[runs[i][2]][name], ev, d)
     if not busy_iv:
         raise RuntimeError("the trace holds no device operation")
     if spans:
@@ -136,6 +152,8 @@ def reduce(profile, device_ids: Sequence[int]) -> dict:
         "window_s": (w1 - w0) * 1e-9,
         "ops": {k: tuple(v) for k, v in ops.items()},
         "modules": {k: tuple(v) for k, v in modules.items()},
+        "module_ops": {m: {k: tuple(v) for k, v in t.items()}
+                       for m, t in module_ops.items()},
         "breakdown": {
             "device_ops": [[k, v[0]] for k, v in top],
             "idle_gaps": idle_gaps,
